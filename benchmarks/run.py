@@ -8,6 +8,8 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import use_compile_cache
+
 from . import (
     ablation_dse,
     adaptive_replan,
@@ -65,6 +67,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    use_compile_cache()
     print("name,us_per_call,derived")
     failed = 0
     for mod in MODULES:

@@ -14,7 +14,7 @@ import time
 import numpy as np
 
 from repro.core import GemmDims, SingleCoreModel
-from repro.core.calibration import _CACHE, calibrate, measure_grid
+from repro.core.calibration import cached_samples, calibrate, measure_grid
 
 from .common import cnn_descriptors, fmt_row
 
@@ -22,10 +22,11 @@ _LAYER_CACHE = os.path.join(os.path.dirname(__file__), "_table3_layers.json")
 
 
 def _real_grid_samples():
-    calibrate(use_cache=True)  # ensures calibration.json exists
-    with open(_CACHE) as f:
-        data = json.load(f)["samples"]
-    return [(GemmDims(**s["dims"]), s["t"]) for s in data]
+    import jax
+
+    calibrate(use_cache=True)  # ensures calibration.json holds this device
+    samples = cached_samples(jax.devices()[0].device_kind)
+    return [(GemmDims(**d), t) for d, t in samples]
 
 
 def _cnn_layer_samples(max_layers=8):

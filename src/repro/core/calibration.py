@@ -14,7 +14,9 @@ and fit the Eq. 5 coefficients.  Multi-core points for the alpha fit are
 controllable in-process; recorded as an adaptation in DESIGN.md §2).
 
 Results are cached in ``calibration.json`` next to this file because the
-measurement sweep takes tens of seconds.
+measurement sweep takes tens of seconds.  Samples are stored under the
+``device_kind`` of the device that measured them, and read back only on
+a device of the same kind.
 """
 from __future__ import annotations
 
@@ -106,21 +108,41 @@ def _synthetic_multicore_samples(
     return out
 
 
+def cached_samples(kind: str) -> Optional[List[Tuple[Dict[str, int], float]]]:
+    """This device kind's cached samples, or None when there are none."""
+    if not os.path.exists(_CACHE):
+        return None
+    with open(_CACHE) as f:
+        entry = json.load(f).get("devices", {}).get(kind)
+    if entry is None:
+        return None
+    return [(s["dims"], s["t"]) for s in entry["samples"]]
+
+
+def _write_samples(kind: str, meas: List[Tuple[Dict[str, int], float]]) -> None:
+    """Store ``meas`` under ``kind``, keeping other kinds' samples."""
+    devices = {}
+    if os.path.exists(_CACHE):
+        with open(_CACHE) as f:
+            devices = json.load(f).get("devices", {})
+    devices[kind] = {"samples": [{"dims": d, "t": t} for d, t in meas]}
+    with open(_CACHE, "w") as f:
+        json.dump({"devices": devices}, f, indent=1)
+
+
 def calibrate(
     use_cache: bool = True,
     tile_size: int = 16,
 ) -> MultiCoreModel:
-    """Fit the Eq. 5/8 model, measuring the host if no cache exists."""
-    meas: List[Tuple[Dict[str, int], float]]
-    if use_cache and os.path.exists(_CACHE):
-        with open(_CACHE) as f:
-            meas = [(s["dims"], s["t"]) for s in json.load(f)["samples"]]
-    else:
+    """Fit the Eq. 5/8 model, measuring this device if no cache for its
+    ``device_kind`` exists."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    meas = cached_samples(kind) if use_cache else None
+    if meas is None:
         meas = measure_grid()
-        with open(_CACHE, "w") as f:
-            json.dump(
-                {"samples": [{"dims": d, "t": t} for d, t in meas]}, f, indent=1
-            )
+        _write_samples(kind, meas)
     samples = [(GemmDims(**d), t) for d, t in meas]
     single = SingleCoreModel.fit(samples)
     multi_samples = _synthetic_multicore_samples(single, samples, tile_size)

@@ -7,7 +7,7 @@ available layer descriptors.  This module does the same for the Pallas
 kernels: every conv layer's `ConvDescriptor` (equivalently its im2col
 GEMM dims, Eq. 4) maps to a cache key; on first sight the tuner sweeps a
 small (bm, bn, bk) candidate grid with best-of-k wall timing and persists
-the winner to a JSON cache, so warmup cost is paid once per platform.
+the winner to a JSON cache, so warmup cost is paid once per device kind.
 
 Two kinds of measurement, both cached:
 
@@ -24,11 +24,12 @@ Two kinds of measurement, both cached:
   (core/perfmodel.py).
 
 Cache file format (``autotune_cache.json`` next to this module, override
-with ``REPRO_AUTOTUNE_CACHE``)::
+with ``REPRO_AUTOTUNE_CACHE``), keyed by ``jax.devices()[0].device_kind``
+so times measured on one device are never read on another::
 
     {"version": 1,
      "platforms": {
-       "cpu": {
+       "TPU v5 lite": {
          "conv_fused/f32/i14x14x256/f3x3/s1/p1/g1/ofm512": {
            "bm": 14, "bn": 128, "bk": 128,
            "time_s": 1.2e-4,     # best sweep candidate seconds
@@ -43,7 +44,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import logging
 import os
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -52,8 +52,6 @@ import numpy as np
 
 from ..core.descriptors import ConvDescriptor
 from .config import default_interpret, on_tpu
-
-logger = logging.getLogger(__name__)
 
 _DEFAULT_CACHE = os.path.join(os.path.dirname(__file__), "autotune_cache.json")
 _ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
@@ -83,17 +81,22 @@ def descriptor_key(desc: ConvDescriptor, op: str = "conv_fused") -> str:
 def candidate_blocks(
     ow: int, cout: int, cin: int, max_candidates: int = 12
 ) -> List[BlockConfig]:
-    """(bm, bn, bk) sweep grid, clipped to the layer's dims and deduped.
+    """(bm, bn, bk) sweep grid, deduped, every block tile-legal on the TPU.
 
-    Power-of-two tiles for the MXU plus half-dim splits so small layers
-    (everything clips to the dim) still have at least two points to
-    sweep.  The untuned heuristic (conv_fused.default_blocks) is always a
-    candidate, so the tuned pick can never lose to it by construction."""
-    from .conv_fused import default_blocks
+    A block's last two dims must be multiples of (8, 128) or span the
+    whole array dim, so ``bm`` is a multiple of 8 or the whole output
+    row, and ``bn``/``bk`` are multiples of 128 or the whole
+    ``cout``/``cin``.  Whole dims are offered only up to 256 output and
+    512 input channels, which keeps the weight block within VMEM.  The
+    half-row ``bm`` (rounded up to 8) gives narrow layers a second point
+    to sweep.  The untuned heuristic (conv_fused.default_blocks) is
+    always a candidate, so the tuned pick can never lose to it by
+    construction."""
+    from .conv_fused import _ceil_to, default_blocks
 
-    bms = sorted({min(ow, v) for v in (32, 128)} | {ow, -(-ow // 2)})
-    bns = sorted({min(cout, v) for v in (64, 128, 256)} | {-(-cout // 2)})
-    bks = sorted({min(cin, v) for v in (32, 128)})
+    bms = sorted({v for v in (32, 128, _ceil_to(-(-ow // 2), 8)) if v < ow} | {ow})
+    bns = sorted({v for v in (128, 256) if v < cout} | ({cout} if cout <= 256 else set()))
+    bks = sorted(({128} if cin > 128 else set()) | ({cin} if cin <= 512 else set()))
     dm, dn, dk = default_blocks(ow, cout, cin)
     # the heuristic lane-rounds above small dims; clamp so every candidate
     # respects the layer's dims (the kernel would clamp identically)
@@ -128,7 +131,7 @@ class ConvAutotuner:
     def __init__(
         self,
         cache_path: Optional[str] = None,
-        platform: Optional[str] = None,
+        device_kind: Optional[str] = None,
         repeats: int = 3,
         sweep: Optional[bool] = None,
         proxy_rows: int = 4,
@@ -136,7 +139,7 @@ class ConvAutotuner:
         import jax
 
         self.cache_path = cache_path or os.environ.get(_ENV_CACHE) or _DEFAULT_CACHE
-        self.platform = platform or jax.default_backend()
+        self.device_kind = device_kind or jax.devices()[0].device_kind
         self.repeats = repeats
         # sweep=None: sweep only where the Pallas kernel really executes
         # (TPU), or when CI forces it; the sweep in interpret mode is a
@@ -167,14 +170,14 @@ class ConvAutotuner:
         return data if isinstance(data, dict) else {}
 
     def load(self) -> None:
-        """Adopt the file's entries for this platform; tolerant of damage
+        """Adopt the file's entries for this device kind; tolerant of damage
         (missing file, invalid JSON, wrong schema) — a broken cache means
         an empty cache, and the tuner re-times on demand."""
         self._entries = {}
         platforms = self._read_cache(self.cache_path).get("platforms", {})
         if not isinstance(platforms, dict):
             return
-        entries = platforms.get(self.platform, {})
+        entries = platforms.get(self.device_kind, {})
         if not isinstance(entries, dict):
             return
         # drop individually-damaged entries (and damaged routes sub-dicts
@@ -198,9 +201,9 @@ class ConvAutotuner:
         if not isinstance(data.get("platforms"), dict):
             data = {"version": 1, "platforms": {}}
         data.setdefault("version", 1)
-        mine = data["platforms"].setdefault(self.platform, {})
+        mine = data["platforms"].setdefault(self.device_kind, {})
         if not isinstance(mine, dict):
-            mine = data["platforms"][self.platform] = {}
+            mine = data["platforms"][self.device_kind] = {}
         for key, entry in self._entries.items():
             hit = mine.get(key)
             if isinstance(hit, dict):  # merge: keep a peer's routes/blocks
@@ -280,6 +283,8 @@ class ConvAutotuner:
         bias = jnp.zeros((ofm,), jnp.float32)
         best_cfg, best_t = None, float("inf")
         cands = candidate_blocks(ow, ofm, c)
+        # every candidate is tile-legal, so one that fails to compile or
+        # run is a kernel bug: it raises rather than leaving the sweep
         for cfg in cands:
             self.timings_run += 1
             try:
@@ -290,17 +295,11 @@ class ConvAutotuner:
                     ).block_until_ready(),
                     self.repeats,
                 )
-            except Exception:  # a candidate the kernel cannot tile
-                logger.debug(
-                    "autotune %s: candidate %s failed to compile/run "
-                    "(dropped from the sweep)", key, cfg, exc_info=True,
-                )
-                continue
+            except Exception as e:
+                e.add_note(f"autotune {key}: sweep candidate {cfg}")
+                raise
             if t < best_t:
                 best_cfg, best_t = cfg, t
-        if best_cfg is None:  # every candidate failed: heuristic fallback
-            best_cfg = BlockConfig(*default_blocks(ow, desc.ofm, desc.i_d))
-            best_t = None
         entry = self._entries.setdefault(key, {})
         entry.update(
             **dataclasses.asdict(best_cfg),

@@ -30,13 +30,12 @@ threads the spec through.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Callable, Dict, Mapping, Optional, Tuple, Union
 
 import jax.numpy as jnp
 
 from .autotune import ConvAutotuner
-from .config import _ENV, on_tpu
+from .config import interpret_requested, on_tpu
 from .conv_fused import conv2d_fused, fused_route_ref, matmul_fused, supports
 
 BACKENDS = ("xla", "pallas", "pallas_fused")
@@ -45,17 +44,18 @@ BackendSpec = Union[str, Mapping[str, str], Callable[[str], str], "KernelBackend
 
 
 def _pallas_active(interpret: Optional[bool]) -> bool:
-    """Should the fused *Pallas kernel* itself execute?  On TPU, always;
+    """Should the fused *Pallas kernel* itself execute?  On TPU, always
+    (and REPRO_PALLAS_INTERPRET asking for the interpreter raises there);
     elsewhere only when interpret mode is explicitly requested (argument
     or REPRO_PALLAS_INTERPRET) — never silently on a serving path.  An
     explicit ``interpret=False`` pins the XLA route off-TPU even under
     the env override."""
+    requested = interpret_requested()
     if on_tpu():
         return True
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get(_ENV, "").strip()
-    return env not in ("", "0", "false", "False")
+    return bool(requested)
 
 
 @dataclasses.dataclass

@@ -5,11 +5,15 @@ The kernels in this package compile to real TPU code; everywhere else
 which executes the kernel body with jax ops grid-step by grid-step — a
 silent ~100x slowdown if it ever lands on a serving hot path.  Entry
 points therefore default ``interpret`` by platform (interpret only
-off-TPU) instead of hard-coding ``True``; ``REPRO_PALLAS_INTERPRET``
-overrides for debugging compiled-vs-interpreted divergence:
+off-TPU) instead of hard-coding ``True``.  Off-TPU,
+``REPRO_PALLAS_INTERPRET`` overrides for validation runs:
 
-    REPRO_PALLAS_INTERPRET=1   force interpret mode everywhere
-    REPRO_PALLAS_INTERPRET=0   force compiled Pallas (requires TPU)
+    REPRO_PALLAS_INTERPRET=1   run the kernels in the interpreter
+    REPRO_PALLAS_INTERPRET=0   compiled Pallas (fails without a TPU)
+
+On a TPU a value asking for interpret mode raises instead of being
+honoured: a chip run never executes the interpreter by way of a
+variable left in the environment.
 
 The serving backends (kernels/backend.py) go one step further and route
 to jnp/XLA equivalents on non-TPU hosts, so interpret mode is reserved
@@ -29,6 +33,21 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def interpret_requested() -> Optional[bool]:
+    """The ``REPRO_PALLAS_INTERPRET`` override: None when unset, else
+    whether it asks for interpret mode.  Raises on a TPU when it does."""
+    env = os.environ.get(_ENV, "").strip()
+    if env == "":
+        return None
+    wants = env not in ("0", "false", "False")
+    if wants and on_tpu():
+        raise RuntimeError(
+            f"{_ENV}={env!r} asks for the Pallas interpreter on a TPU; "
+            "unset it to run the compiled kernels"
+        )
+    return wants
+
+
 def default_interpret(interpret: Optional[bool] = None) -> bool:
     """Resolve an entry point's ``interpret`` argument.
 
@@ -37,7 +56,7 @@ def default_interpret(interpret: Optional[bool] = None) -> bool:
     """
     if interpret is not None:
         return interpret
-    env = os.environ.get(_ENV)
-    if env is not None and env.strip() != "":
-        return env.strip() not in ("0", "false", "False")
+    env = interpret_requested()
+    if env is not None:
+        return env
     return not on_tpu()

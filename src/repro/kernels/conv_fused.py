@@ -17,11 +17,13 @@ stays resident in VMEM scratch across the whole reduction.  The M tile
 analogue), ``bn`` tiles output channels, ``bk`` tiles input channels;
 (bm, bn, bk) is what `kernels/autotune.py` sweeps.
 
-Block-wise patch formation: for output row ``oh`` and filter row ``fi``
-the kernel loads padded input row ``oh*stride + fi`` (one [Wp, bk] VMEM
-block), takes the ``bm``-column window at ``jm*bm*stride``, and emits the
-``fw`` strided slices whose concatenation is the [bm, fw*bk] patch block
-— feature order (fw, c), matching ``w.reshape(fh, fw, c, cout)`` blocks.
+Block-wise patch formation: the wrapper splits each padded input row by
+stride phase (column ``m*stride + r`` -> phase ``r``, position ``m``).
+For output row ``oh`` and filter row ``fi`` the kernel holds padded input
+row ``oh*stride + fi`` (one [stride, Wq, bk] VMEM block); filter column
+``j = q*stride + r`` of the ``bm``-column tile at ``jm*bm`` is then the
+contiguous window ``[jm*bm + q, +bm)`` of phase ``r``, and its
+[bm, bk] x [bk, bn] product accumulates into the tile.
 
 Off-TPU the Pallas kernel only runs under the interpreter (validation,
 ~100x), so `fused_route` resolves to the XLA equivalent — a direct
@@ -37,44 +39,43 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .config import default_interpret
-
-try:  # TPU memory spaces; harmless on CPU interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except (ImportError, AttributeError):  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 
 # --------------------------------------------------------------- kernel body
 def _conv_fused_kernel(
     x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref,
-    *, fw: int, stride: int, bm: int, n_k: int, relu: bool,
+    *, fw: int, stride: int, bm: int, n_m: int, ext: int, n_k: int, relu: bool,
 ):
     k = pl.program_id(4)
-    jm = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    row = x_ref[0, 0]  # [Wp_ext, bk]: padded input row, one channel block
-    bk = row.shape[1]
-    # implicit im2col: the bm-column output window needs input columns
-    # [jm*bm*stride, jm*bm*stride + (bm-1)*stride + fw)
-    seg = jax.lax.dynamic_slice(
-        row, (jm * bm * stride, 0), ((bm - 1) * stride + fw, bk)
-    )
-    cols = [
-        jax.lax.slice(seg, (j, 0), (j + stride * (bm - 1) + 1, bk), (stride, 1))
-        for j in range(fw)
-    ]
-    patch = jnp.concatenate(cols, axis=1)  # [bm, fw*bk], features (fw, c)
-    wblk = w_ref[0].reshape(fw * bk, -1)  # [fw*bk, bn], same (fw, c) order
-    acc_ref[...] += jnp.dot(patch, wblk, preferred_element_type=acc_ref.dtype)
+    # implicit im2col: output column o reads input column o*stride + j for
+    # filter column j = q*stride + r, which is column o + q of stride
+    # phase r — so every window is a contiguous [bm, bk] run of the row.
+    # The tile starts at jm*bm, which the chip reads dynamically only at
+    # a multiple of 8 (candidate_blocks keeps a split row's bm one): read
+    # [jm*bm, +bm+ext) once per phase and slice the q offsets from it.
+    m0 = 0
+    if n_m > 1:
+        m0 = pl.program_id(2) * bm
+        if bm % 8 == 0:
+            m0 = pl.multiple_of(m0, 8)
+    phase = {}
+    acc = None
+    for j in range(fw):
+        q, r = divmod(j, stride)
+        if r not in phase:
+            phase[r] = x_ref[0, 0, r, pl.ds(m0, bm + ext), :]
+        win = phase[r][q:q + bm]  # [bm, bk]
+        part = jnp.dot(win, w_ref[0, j], preferred_element_type=acc_ref.dtype)
+        acc = part if acc is None else acc + part
+    acc_ref[...] += acc
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -129,31 +130,34 @@ def _conv_fused_call(
     n_m, n_n, n_kc = -(-ow // bm), -(-cout // bn), -(-c // bk)
     n_k = fh * n_kc
     # pad so every tile is full: channels to bk, filters to (bn, bk), and
-    # the input rows wide enough for the last column tile's window
-    wp_ext = max(wp, (n_m * bm - 1) * stride + fw)
-    xp = _pad_axis(_pad_axis(xp, 3, n_kc * bk), 2, wp_ext)
+    # each stride phase long enough for the last column tile's window
+    # (the kernel's aligned read overhangs a tile by ext columns)
+    ext = _ceil_to((fw - 1) // stride, 8)
+    wq = max(-(-wp // stride), n_m * bm + ext)
+    xp = _pad_axis(_pad_axis(xp, 3, n_kc * bk), 2, wq * stride)
+    # split columns by stride phase: xph[b, h, r, m] = xp[b, h, m*stride + r]
+    xph = xp.reshape(b, hp, wq, stride, n_kc * bk).transpose(0, 1, 3, 2, 4)
     w4 = _pad_axis(_pad_axis(w4, 2, n_kc * bk), 3, n_n * bn)
     scale2 = _pad_axis(scale.reshape(1, -1).astype(jnp.float32), 1, n_n * bn)
     bias2 = _pad_axis(bias.reshape(1, -1).astype(jnp.float32), 1, n_n * bn)
 
     acc_dtype = jnp.int32 if jnp.issubdtype(xp.dtype, jnp.integer) else jnp.float32
-    scratch = (
-        [pltpu.VMEM((bm, bn), acc_dtype)]
-        if _VMEM is not None
-        else [pl.MemorySpace.ANY]
-    )
     out = pl.pallas_call(
         functools.partial(
             _conv_fused_kernel,
-            fw=fw, stride=stride, bm=bm, n_k=n_k, relu=relu,
+            fw=fw, stride=stride, bm=bm, n_m=n_m, ext=ext, n_k=n_k,
+            relu=relu,
         ),
         grid=(b, oh, n_m, n_n, n_k),
         in_specs=[
-            # one padded input row (block height 1 => element row index),
-            # channel block k % n_kc, at filter row fi = k // n_kc
+            # one padded input row, all stride phases (block height 1 =>
+            # element row index), channel block k % n_kc, at filter row
+            # fi = k // n_kc
             pl.BlockSpec(
-                (1, 1, wp_ext, bk),
-                lambda bi, i, jm, j, k, s=stride: (bi, i * s + k // n_kc, 0, k % n_kc),
+                (1, 1, stride, wq, bk),
+                lambda bi, i, jm, j, k, s=stride: (
+                    bi, i * s + k // n_kc, 0, 0, k % n_kc
+                ),
             ),
             pl.BlockSpec(
                 (1, fw, bk, bn),
@@ -164,9 +168,9 @@ def _conv_fused_call(
         ],
         out_specs=pl.BlockSpec((1, 1, bm, bn), lambda bi, i, jm, j, k: (bi, i, jm, j)),
         out_shape=jax.ShapeDtypeStruct((b, oh, n_m * bm, n_n * bn), out_dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=interpret,
-    )(xp, w4, scale2, bias2)
+    )(xph, w4, scale2, bias2)
     return out[:, :, :ow, :cout]
 
 
@@ -301,11 +305,6 @@ def matmul_fused(
     bias2 = _pad_axis(bias.reshape(1, -1).astype(jnp.float32), 1, w_p.shape[1])
     n_k = a_p.shape[1] // bk
     grid = (a_p.shape[0] // bm, w_p.shape[1] // bn, n_k)
-    scratch = (
-        [pltpu.VMEM((bm, bn), jnp.float32)]
-        if _VMEM is not None
-        else [pl.MemorySpace.ANY]
-    )
     out = pl.pallas_call(
         functools.partial(_matmul_fused_kernel, n_k=n_k, relu=relu),
         grid=grid,
@@ -317,7 +316,7 @@ def matmul_fused(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((a_p.shape[0], w_p.shape[1]), a.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a_p, w_p, ones, bias2)
     return out[:m, :n]

@@ -21,16 +21,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .config import default_interpret
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except (ImportError, AttributeError):  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 _NEG_INF = -1e30
 
@@ -95,15 +88,11 @@ def flash_decode(
     scale = 1.0 / (d ** 0.5)
     len_arr = jnp.asarray(length, jnp.int32).reshape(1, 1)
 
-    scratch = (
-        [
-            _VMEM((hq, 1), jnp.float32),
-            _VMEM((hq, 1), jnp.float32),
-            _VMEM((hq, d), jnp.float32),
-        ]
-        if _VMEM is not None
-        else [pl.MemorySpace.ANY] * 3
-    )
+    scratch = [
+        pltpu.VMEM((hq, 1), jnp.float32),
+        pltpu.VMEM((hq, 1), jnp.float32),
+        pltpu.VMEM((hq, d), jnp.float32),
+    ]
     return pl.pallas_call(
         functools.partial(_flash_decode_kernel, bs=bs, n_b=n_b, scale=scale),
         grid=(n_b,),

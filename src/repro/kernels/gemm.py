@@ -16,16 +16,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .config import default_interpret
-
-try:  # TPU memory spaces; harmless on CPU interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except (ImportError, AttributeError):  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 
 def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -85,11 +78,6 @@ def gemm(
     n_k = kp // bk
     grid = (mp // bm, np_ // bn, n_k)
 
-    scratch = (
-        [pltpu.VMEM((bm, bn), jnp.float32)]
-        if _VMEM is not None
-        else [pl.MemorySpace.ANY]
-    )
     out = pl.pallas_call(
         functools.partial(_gemm_kernel, n_k=n_k),
         grid=grid,
@@ -99,7 +87,7 @@ def gemm(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), a.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a_p, b_p)
     return out[:m, :n]

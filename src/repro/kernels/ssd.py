@@ -17,16 +17,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .config import default_interpret
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except (ImportError, AttributeError):  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 
 def _ssd_kernel(x_ref, la_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref, h_ref, *, n_c):
@@ -97,9 +90,7 @@ def ssd(
     n_c = s // q
     grid = (h, n_c)
 
-    scratch = (
-        [_VMEM((n, p), jnp.float32)] if _VMEM is not None else [pl.MemorySpace.ANY]
-    )
+    scratch = [pltpu.VMEM((n, p), jnp.float32)]
     y, h_out = pl.pallas_call(
         functools.partial(_ssd_kernel, n_c=n_c),
         grid=grid,

@@ -10,7 +10,13 @@ chips.  Batch is sharded over ("pod", "data"); weights/experts/heads over
 """
 from __future__ import annotations
 
-from ..compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto (sharding left to the compiler)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

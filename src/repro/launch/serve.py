@@ -21,8 +21,11 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    from ..compile_cache import use_compile_cache
     from ..configs import get_config
     from ..models import init_cache, init_params, prefill, serve_step
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -28,12 +28,14 @@ def main() -> None:
 
     import jax
 
+    from ..compile_cache import use_compile_cache
     from ..configs import get_config
     from ..data import make_batch_iterator
     from ..models import init_params
     from ..optim import adamw_init
     from .steps import make_train_step
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from .attention import blockwise_attention, decode_attention, rope
 from .config import ModelConfig
 from .moe import init_moe_params, moe_expert_parallel, moe_local
@@ -232,7 +230,7 @@ def _seqsharded_decode(ctx: MeshCtx, q, ck, cv, cpos, length, window,
         scale_spec = P(ax)
     else:
         scale_spec = P(dp, ax, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=ctx.mesh,
         in_specs=(
@@ -312,7 +310,7 @@ def moe_apply(cfg: ModelConfig, ctx, p, x):
 
         especs = jax.tree.map(lambda _: P(ax), p)
         especs["router"] = P()
-        return shard_map(
+        return jax.shard_map(
             f, mesh=ctx.mesh,
             in_specs=(especs, P(dp, None, None)),
             out_specs=(P(dp, None, None), P()),

@@ -25,8 +25,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
-
 
 # ----------------------------------------------------------------- router
 def router(x_flat: jnp.ndarray, w_router: jnp.ndarray, top_k: int, renorm: bool = True):
@@ -125,7 +123,7 @@ def moe_expert_parallel(
     waste at M=16), and the disjoint outputs are all-gathered at the end.
     """
     b, s, d = x.shape
-    m = axis_size(axis_name)
+    m = jax.lax.axis_size(axis_name)
     m_idx = jax.lax.axis_index(axis_name)
     e_loc = params["w1"].shape[0]
     e = e_loc * m

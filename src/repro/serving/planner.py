@@ -335,7 +335,7 @@ def serve(
     :mod:`repro.kernels.backend`).  ``autotune=True`` attaches a
     :class:`repro.kernels.autotune.ConvAutotuner` (or pass an existing
     one via ``tuner``): the tuner measures each layer's serving route
-    once (JSON-cached per platform), picks fused block sizes, and the
+    once (JSON-cached per device kind), picks fused block sizes, and the
     planner's time matrix is built from those measurements instead of
     the Eq. 5 regression alone — so the DSE balances stages by the
     kernels that actually run.

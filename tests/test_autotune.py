@@ -53,6 +53,51 @@ def test_candidate_blocks_clipped_to_dims():
     assert len(candidate_blocks(14, 48, 20)) >= 2  # something to sweep
 
 
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_candidate_blocks_tile_legal_for_zoo(name):
+    """Every block the sweep offers obeys the TPU's (8, 128) tiling rule:
+    bm a multiple of 8 or the whole output row, bn/bk multiples of 128
+    or the whole cout/cin."""
+    for d in MODELS[name]().descriptors():
+        if d.kind != "conv" or d.groups != 1:
+            continue
+        ow = d.output_shape()[0]
+        for c in candidate_blocks(ow, d.ofm, d.i_d):
+            assert c.bm % 8 == 0 or c.bm == ow, (d.name, c)
+            assert c.bn % 128 == 0 or c.bn == d.ofm, (d.name, c)
+            assert c.bk % 128 == 0 or c.bk == d.i_d, (d.name, c)
+
+
+def test_sweep_candidate_failure_raises(tmp_path, monkeypatch):
+    """A candidate that fails to compile or run stops the sweep; it is
+    not dropped, and nothing is recorded as swept."""
+    import repro.kernels.conv_fused as conv_fused
+
+    def refused(*args, **kwargs):
+        raise RuntimeError("refused by the compiler")
+
+    monkeypatch.setattr(conv_fused, "conv2d_fused", refused)
+    t = ConvAutotuner(cache_path=str(tmp_path / "tune.json"), sweep=True,
+                      repeats=1, proxy_rows=2)
+    with pytest.raises(RuntimeError, match="refused by the compiler"):
+        t.tune(TINY)
+    assert t.entry(TINY) is None
+
+
+def test_cache_is_keyed_by_device_kind(tmp_path):
+    """Times written for one device kind are never read on another."""
+    cache = str(tmp_path / "tune.json")
+    cpu = ConvAutotuner(cache_path=cache, device_kind="cpu", sweep=False, repeats=1)
+    cpu.measure_route(TINY, lambda: None, route="xla")
+    again = ConvAutotuner(cache_path=cache, device_kind="cpu", sweep=False)
+    assert again.measured_route(TINY, "xla") is not None
+    tpu = ConvAutotuner(cache_path=cache, device_kind="TPU v5 lite", sweep=False)
+    assert tpu.entry(TINY) is None
+    assert ConvAutotuner(cache_path=cache, sweep=False).device_kind == (
+        jax.devices()[0].device_kind
+    )
+
+
 def test_sweep_cache_round_trip_zero_retiming(tmp_path):
     cache = str(tmp_path / "tune.json")
     t1 = ConvAutotuner(cache_path=cache, sweep=True, repeats=1, proxy_rows=2)
@@ -71,7 +116,7 @@ def test_sweep_cache_round_trip_zero_retiming(tmp_path):
     with open(cache) as f:
         data = json.load(f)
     assert data["version"] == 1
-    assert descriptor_key(TINY) in data["platforms"][jax.default_backend()]
+    assert descriptor_key(TINY) in data["platforms"][jax.devices()[0].device_kind]
 
 
 def test_route_measurement_cached(tmp_path):
@@ -185,7 +230,7 @@ def test_damaged_routes_field_inside_healthy_entry(tmp_path):
     cache = tmp_path / "tune.json"
     cache.write_text(json.dumps({
         "version": 1,
-        "platforms": {jax.default_backend(): {descriptor_key(TINY): {
+        "platforms": {jax.devices()[0].device_kind: {descriptor_key(TINY): {
             "swept": False, "candidates": 0, "routes": 7,
         }}},
     }))

@@ -35,6 +35,18 @@ def test_serve_cli_smoke():
     assert "decode:" in out and "tok/s" in out
 
 
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py has no CPU fallback: with no TPU it exits non-zero,
+    says so, and prints no result line."""
+    res = subprocess.run(
+        [sys.executable, "chip_smoke.py"], env=dict(ENV, JAX_PLATFORMS="cpu"),
+        cwd=REPO, capture_output=True, text=True, timeout=120,
+    )
+    assert res.returncode != 0
+    assert "no TPU found" in res.stderr
+    assert '"ok"' not in res.stdout
+
+
 def test_quickstart_example():
     out = _run([sys.executable, "examples/quickstart.py"])
     assert "Pipe-it chose:" in out

@@ -191,6 +191,26 @@ def test_interpret_default_follows_platform(monkeypatch):
     assert default_interpret(None) is True
 
 
+@pytest.mark.parametrize("value", ["1", "true"])
+def test_interpret_override_raises_on_tpu(monkeypatch, value):
+    """On a TPU an env value asking for the interpreter raises, in the
+    kernel entry points and on the serving backend, instead of running
+    the kernels interpreted."""
+    import repro.kernels.backend as backend_mod
+    import repro.kernels.config as config_mod
+
+    monkeypatch.setattr(config_mod, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend_mod, "on_tpu", lambda: True)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", value)
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        default_interpret(None)
+    x, w, b = _arr((1, 6, 6, 2)), _arr((3, 3, 2, 4)), _arr((4,))
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
+        resolve_backend("pallas_fused").conv2d("c", x, w, b, pad=1, relu=True)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert default_interpret(None) is False  # compiled, as on any TPU run
+
+
 # ------------------------------------- acceptance: per-node graph parity
 def _full_env(graph, params, x, backend):
     """Execute every node, keeping ALL intermediate tensors (no pruning)."""

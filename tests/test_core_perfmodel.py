@@ -163,3 +163,41 @@ def test_predict_positive_and_monotone_in_cores(n, k, m, h):
     t_h1 = model.predict(g, h + 1)
     assert t_h > 0
     assert t_h1 <= t_h * 1.001  # more cores never (meaningfully) hurt
+
+
+# ------------------------------------------------- calibration cache keying
+def test_calibration_cache_is_keyed_by_device_kind(tmp_path, monkeypatch):
+    """Samples measured on one device kind are never read on another: a
+    cache written for "cpu" makes a TPU measure its own grid."""
+    import types
+
+    import jax
+
+    import repro.core.calibration as cal
+
+    monkeypatch.setattr(cal, "_CACHE", str(tmp_path / "calibration.json"))
+    grid = [d.gemm_dims() for d in microbenchmark_grid()]
+    measured = []
+
+    def fake_measure_grid():
+        t = 1e-4 * (len(measured) + 1)
+        measured.append(t)
+        return [({"N": g.N, "K": g.K, "M": g.M}, t * g.macs / 1e6) for g in grid]
+
+    monkeypatch.setattr(cal, "measure_grid", fake_measure_grid)
+
+    def on_kind(kind):
+        monkeypatch.setattr(
+            jax, "devices", lambda: [types.SimpleNamespace(device_kind=kind)]
+        )
+
+    on_kind("cpu")
+    cal.calibrate()
+    assert len(measured) == 1
+    cal.calibrate()  # cache hit for the same kind
+    assert len(measured) == 1
+    on_kind("TPU v5 lite")
+    assert cal.cached_samples("TPU v5 lite") is None
+    cal.calibrate()
+    assert len(measured) == 2  # measured anew, not read from the cpu entry
+    assert cal.cached_samples("cpu") != cal.cached_samples("TPU v5 lite")

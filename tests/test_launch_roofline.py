@@ -130,7 +130,7 @@ def test_mini_dryrun_8_fake_devices(shape_kind):
         cfg = get_config("olmoe-1b-7b").reduced()
         cfg = dataclasses.replace(cfg, d_model=256, n_heads=4, n_kv_heads=4,
                                   head_dim=64, grad_accum=1)
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         ctx = MeshCtx(mesh=mesh, batch_axes=batch_axes(mesh))
         params_abs = abstract_params(cfg)

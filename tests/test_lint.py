@@ -58,7 +58,7 @@ def test_registry_has_all_issue_rules():
 
 def test_unknown_select_raises():
     with pytest.raises(ValueError, match="no-such-rule"):
-        run_lint([REPO / "src" / "repro" / "compat.py"], select=["no-such-rule"])
+        run_lint([REPO / "src" / "repro" / "launch" / "mesh.py"], select=["no-such-rule"])
 
 
 # ----------------------------------------------------------- wall-clock

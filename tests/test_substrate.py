@@ -157,14 +157,14 @@ def test_ssd_grads_flow():
 def test_moe_local_vs_expert_parallel_exact():
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     D, F, E, K = 16, 32, 4, 2
     params = init_moe_params(jax.random.PRNGKey(0), D, F, E, jnp.float32)
     x = _arr((2, 8, D))
     y1, aux1 = moe_local(params, x, top_k=K, capacity_factor=8.0)
     mesh = make_mesh((1,), ("model",))
-    ep = shard_map(
+    ep = jax.shard_map(
         lambda p, xx: moe_expert_parallel(
             p, xx, axis_name="model", top_k=K, capacity_factor=8.0
         ),
