@@ -38,12 +38,14 @@ class MicroBatch:
 
     ``tickets`` carries the per-image bookkeeping (request ids / futures)
     in row order; ``env`` maps tensor names to arrays whose leading
-    dimension is the padded batch size.
+    dimension is the padded batch size; ``batch`` is the server's
+    micro-batch number, which its spans carry.
     """
 
     tickets: Tuple[Any, ...]
     env: Env
     valid: int
+    batch: int
 
     @property
     def padded(self) -> int:

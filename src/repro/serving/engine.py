@@ -43,7 +43,7 @@ StageFn = Callable[..., Dict[str, jnp.ndarray]]
 def build_stage_fns(
     graph: Graph, plan: PipelinePlan, backend=None
 ) -> List[StageFn]:
-    """One jitted function per pipeline stage.
+    """One jitted function per pipeline stage, named ``stage_{k}``.
 
     Each function executes the stage's contiguous node range against a
     live-tensor env and returns the pruned env that crosses the stage
@@ -60,16 +60,22 @@ def build_stage_fns(
     from ..kernels.backend import resolve_backend
 
     kb = resolve_backend(backend)
-    fns: List[StageFn] = []
-    for start, stop in graph.stage_slices(plan.allocation):
-        fns.append(
-            jax.jit(
-                lambda p, env, s=start, e=stop: graph.apply_range(
-                    p, env, s, e, backend=kb
-                )
-            )
-        )
-    return fns
+    return [
+        _stage_fn(graph, k, start, stop, kb)
+        for k, (start, stop) in enumerate(graph.stage_slices(plan.allocation))
+    ]
+
+
+def _stage_fn(graph: Graph, k: int, start: int, stop: int, kb) -> StageFn:
+    """Stage ``k``'s program, jitted under the name ``stage_{k}``: the
+    profiler's host spans read ``PjitFunction(stage_{k})`` and the device
+    plane's module ``jit_stage_{k}``."""
+
+    def stage(p, env):
+        return graph.apply_range(p, env, start, stop, backend=kb)
+
+    stage.__name__ = stage.__qualname__ = f"stage_{k}"
+    return jax.jit(stage)
 
 
 class SingleStageEngine:

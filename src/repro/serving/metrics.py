@@ -20,6 +20,15 @@ persistent server must not grow memory with uptime), so the percentiles
 describe recent behaviour — which is what an operator watches anyway.
 ``snapshot()`` is safe to call while the server is running (workers only
 append).
+
+The server's threads also mark where their host time goes with spans
+(``span``, names below) on the profiler's clock: a
+``jax.profiler.TraceAnnotation`` lands on the host plane of the same trace
+as the device's ops, so a reader can set the device's idle time against
+what each thread was doing.  Spans are always emitted; with no profiler
+running one costs about a microsecond.  They are leaves: no span of the
+vocabulary opens inside another on the same thread, so a span's duration
+is its own time.
 """
 from __future__ import annotations
 
@@ -29,6 +38,8 @@ import threading
 import time
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from ..core.queueing import empirical_percentile
 
 # Sliding-window sizes for latency samples (per stage / end-to-end).
@@ -37,6 +48,45 @@ E2E_WINDOW = 8192
 # Retired-epoch snapshots kept after plan hot-swaps (bounded for the same
 # reason as the latency windows: uptime must not grow memory).
 EPOCH_HISTORY = 64
+
+# Span vocabulary.  Ids tie one request's and one micro-batch's spans
+# together: ``ticket=`` is ``Ticket.id``, ``batch=`` the server's
+# micro-batch number (``MicroBatch.batch``, assigned at stacking).
+#: ``submit()``: the image to the device with its batch dimension (ticket)
+TO_DEVICE = "serve.to_device"
+#: ``submit()``: the submit lock and the ingress put, blocked while the
+#: ingress is full (ticket)
+ADMIT = "serve.admit"
+#: stage 0: waiting for a micro-batch's first image and its flush window
+GATHER = "serve.gather"
+#: stage 0: concatenating and padding the images (batch, n: valid rows)
+STACK = "serve.stack"
+#: the egress worker: splitting the last stage's output into rows (batch)
+EGRESS = "serve.egress"
+#: the egress worker, per ticket: its completion stamp, waking its client
+#: and running the client's done-callbacks (ticket, batch: which
+#: micro-batch carried the request)
+RESOLVE = "serve.resolve"
+
+StageSpans = collections.namedtuple("StageSpans", "take dispatch wait handoff")
+StageSpans.__doc__ = """Span names of pipeline stage ``k``, ``serve.stage{k}.<kind>``:
+``take`` waits on the upstream queue (k >= 1), ``dispatch`` calls the stage
+program until it returns, ``wait`` blocks until its output is ready, and
+``handoff`` puts the micro-batch on the next queue (all but ``take`` carry
+``batch``).  The stage is in the name because the profiler puts every
+Python thread's spans on one host line."""
+
+
+def stage_spans(k: int) -> StageSpans:
+    """The span names of stage ``k``; a worker builds them once."""
+    return StageSpans(*(f"serve.stage{k}.{kind}" for kind in StageSpans._fields))
+
+
+def span(name: str, **ids: int) -> TraceAnnotation:
+    """A host span ``name`` on the profiler's clock, with integer ``ids``
+    as its stats; use as a context manager.  The one place the serving
+    layer names its tracer."""
+    return TraceAnnotation(name, **ids)
 
 
 def percentile(samples: Sequence[float], q: float) -> float:

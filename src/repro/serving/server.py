@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import itertools
 import logging
+import math
 import queue
 import threading
 import time
@@ -44,7 +45,10 @@ from ..core.pipeline import PipelinePlan
 from .batching import MicroBatch, gather, split_rows, stack_envs
 from .engine import build_stage_fns
 from .faults import RecoveryPolicy, TransientStageError
-from .metrics import ServerMetrics
+from .metrics import (
+    ADMIT, EGRESS, GATHER, RESOLVE, STACK, TO_DEVICE, ServerMetrics, span,
+    stage_spans,
+)
 
 _SENTINEL = object()
 
@@ -248,6 +252,9 @@ class PipelineServer:
         # so a stalled thread abandoned by the watchdog can never corrupt
         # the stream its replacement re-dispatched.
         self._gen_seq = itertools.count(1)
+        # Micro-batch numbers, assigned as stage 0 stacks a batch: the
+        # ``batch`` id of its spans.
+        self._batch_seq = itertools.count()
         self._stage_gen: List[int] = []
         self._processing: List[Optional[Any]] = []  # in-flight work, per stage
         self._busy_since: List[Optional[float]] = []  # heartbeat timestamps
@@ -383,20 +390,23 @@ class PipelineServer:
         if recovered is not None:
             self.metrics.recovery.note_recovered(recovered)
 
-    def _execute(self, si: int, gen: int, fn, env):
+    def _execute(self, si: int, gen: int, fn, env, names, batch: int):
         """Run one stage invocation with the transient-retry loop.
 
         :class:`TransientStageError` retries in place with exponential
         backoff up to ``recovery.max_retries``, then escalates (re-raise
         -> worker restart + re-dispatch).  ``_busy_since`` brackets the
-        call so the watchdog sees a heartbeat per invocation."""
+        call so the watchdog sees a heartbeat per invocation; each attempt
+        spans its dispatch and its wait (``names``: the stage's spans)."""
         policy = self.recovery
         attempt = 0
         while True:
             self._mark_busy(si, gen)
             try:
-                out = fn(self.params, env)
-                jax.block_until_ready(out)
+                with span(names.dispatch, batch=batch):
+                    out = fn(self.params, env)
+                with span(names.wait, batch=batch):
+                    jax.block_until_ready(out)
                 return out
             except TransientStageError:
                 attempt += 1
@@ -809,53 +819,59 @@ class PipelineServer:
         """
         if not self._started and not self._closed:
             self.start()
-        x = jnp.asarray(image, jnp.float32)
-        if x.ndim == len(self.graph.input_shape):
-            x = x[None]
-        if x.shape != (1, *self.graph.input_shape):
+        shape = np.shape(image)
+        if len(shape) == len(self.graph.input_shape):
+            shape = (1, *shape)
+        if tuple(shape) != (1, *self.graph.input_shape):
             raise ValueError(
                 f"submit() takes ONE image of shape {self.graph.input_shape} "
-                f"(optionally with a leading batch dim of 1), got {x.shape}; "
+                f"(optionally with a leading batch dim of 1), got {tuple(shape)}; "
                 "the server forms micro-batches itself"
             )
+        ticket = Ticket(submitted_at=math.nan)  # stamped once x is made
+        with span(TO_DEVICE, ticket=ticket.id):
+            x = jnp.asarray(image, jnp.float32)
+            if x.ndim == len(self.graph.input_shape):
+                x = x[None]
         now = time.perf_counter()
-        ticket = Ticket(submitted_at=now)
-        # Honour the non-blocking/timeout contract on the submit lock too:
-        # during a swap_plan drain the lock is held for the whole drain, and
-        # a submit(block=False) / submit(timeout=...) must shed load rather
-        # than stall behind it.  Ordinary peer submits hold the lock only
-        # microseconds, so a short bounded acquire absorbs that contention
-        # without spurious Backpressure.
-        if block:
-            acquired = self._submit_lock.acquire(
-                timeout=-1 if timeout is None else timeout
-            )
-        elif self._sealed:
-            acquired = False  # drain in progress: shed with zero wait
-        else:
-            acquired = self._submit_lock.acquire(timeout=0.05)
-        if not acquired:
-            raise Backpressure(
-                "pipeline busy (plan swap or shutdown in progress)"
-            )
-        try:
-            with self._lock:
-                if self._closed or self._error is not None:
-                    raise ServerClosed("server is closed") from self._error
-                self._inflight.add(ticket)
-            if timeout is not None:
-                timeout = max(0.0, timeout - (time.perf_counter() - now))
-            try:
-                self._ingress.put((ticket, x), block=block, timeout=timeout)
-            except queue.Full:
-                with self._lock:
-                    self._inflight.discard(ticket)
+        ticket.submitted_at = now
+        with span(ADMIT, ticket=ticket.id):
+            # Honour the non-blocking/timeout contract on the submit lock
+            # too: during a swap_plan drain the lock is held for the whole
+            # drain, and a submit(block=False) / submit(timeout=...) must
+            # shed load rather than stall behind it.  Ordinary peer submits
+            # hold the lock only microseconds, so a short bounded acquire
+            # absorbs that contention without spurious Backpressure.
+            if block:
+                acquired = self._submit_lock.acquire(
+                    timeout=-1 if timeout is None else timeout
+                )
+            elif self._sealed:
+                acquired = False  # drain in progress: shed with zero wait
+            else:
+                acquired = self._submit_lock.acquire(timeout=0.05)
+            if not acquired:
                 raise Backpressure(
-                    f"ingress full ({self._ingress.maxsize} images) — pipeline "
-                    "saturated"
-                ) from None
-        finally:
-            self._submit_lock.release()
+                    "pipeline busy (plan swap or shutdown in progress)"
+                )
+            try:
+                with self._lock:
+                    if self._closed or self._error is not None:
+                        raise ServerClosed("server is closed") from self._error
+                    self._inflight.add(ticket)
+                if timeout is not None:
+                    timeout = max(0.0, timeout - (time.perf_counter() - now))
+                try:
+                    self._ingress.put((ticket, x), block=block, timeout=timeout)
+                except queue.Full:
+                    with self._lock:
+                        self._inflight.discard(ticket)
+                    raise Backpressure(
+                        f"ingress full ({self._ingress.maxsize} images) — "
+                        "pipeline saturated"
+                    ) from None
+            finally:
+                self._submit_lock.release()
         # close the submit()/_fail() race: if a worker failed while we were
         # enqueueing, nothing will ever consume the item — fail the ticket
         # now instead of letting the caller block until timeout
@@ -914,6 +930,7 @@ class PipelineServer:
         fn = self._stage_fns[0]
         m = self.metrics.stages[0]
         qs = self._qs  # epoch-bound: a zombie must not touch new queues
+        names = stage_spans(0)
         try:
             redo = self._take_redispatch(0, gen)
             if redo is not None:
@@ -923,10 +940,11 @@ class PipelineServer:
                     items, eof = redo, False
                     redo = None
                 else:
-                    items, eof = gather(
-                        self._ingress, self.batch_size, self.flush_timeout_s,
-                        _SENTINEL,
-                    )
+                    with span(GATHER):
+                        items, eof = gather(
+                            self._ingress, self.batch_size, self.flush_timeout_s,
+                            _SENTINEL,
+                        )
                     if items:
                         self._set_processing(0, gen, items)
                 if items:
@@ -936,12 +954,14 @@ class PipelineServer:
                         if t.dequeued_at is None:  # not restamped on re-dispatch
                             t.dequeued_at = t0
                             self.metrics.note_dequeue(t.submitted_at, t0)
-                    env = stack_envs(
-                        [{"input": x} for _, x in items], pad_to=self.batch_size
-                    )
+                    batch = next(self._batch_seq)
+                    with span(STACK, batch=batch, n=len(items)):
+                        env = stack_envs(
+                            [{"input": x} for _, x in items], pad_to=self.batch_size
+                        )
                     # materialize before handing off: the stage boundary is
                     # where the activation crosses clusters in the paper
-                    out = self._execute(0, gen, fn, env)
+                    out = self._execute(0, gen, fn, env, names, batch)
                     t1 = time.perf_counter()
                     if not self._gen_current(0, gen):
                         return  # declared stalled; replacement re-dispatched
@@ -949,9 +969,9 @@ class PipelineServer:
                         m.started_at = t0
                     m.stopped_at = t1
                     m.record(t1 - t0, len(items), self.batch_size - len(items))
-                    ok = self._forward(
-                        qs[0], MicroBatch(tickets, out, valid=len(items)), 0, gen
-                    )
+                    mb = MicroBatch(tickets, out, valid=len(items), batch=batch)
+                    with span(names.handoff, batch=batch):
+                        ok = self._forward(qs[0], mb, 0, gen)
                     self._clear_processing(0, gen)
                     if not ok:
                         return
@@ -965,19 +985,21 @@ class PipelineServer:
         fn = self._stage_fns[si]
         m = self.metrics.stages[si]
         qs = self._qs  # epoch-bound: a zombie must not touch new queues
+        names = stage_spans(si)
         try:
             item = self._take_redispatch(si, gen)
             if item is not None:
                 self.metrics.recovery.note_redispatch(item.valid)
             while True:
                 if item is None:
-                    item = qs[si - 1].get()
+                    with span(names.take):
+                        item = qs[si - 1].get()
                     if item is _SENTINEL:
                         self._forward(qs[si], _SENTINEL, si, gen)
                         return
                     self._set_processing(si, gen, item)
                 t0 = time.perf_counter()
-                out = self._execute(si, gen, fn, item.env)
+                out = self._execute(si, gen, fn, item.env, names, item.batch)
                 t1 = time.perf_counter()
                 if not self._gen_current(si, gen):
                     return  # declared stalled; replacement re-dispatched
@@ -985,9 +1007,9 @@ class PipelineServer:
                     m.started_at = t0
                 m.stopped_at = t1
                 m.record(t1 - t0, item.valid, item.padded)
-                ok = self._forward(
-                    qs[si], MicroBatch(item.tickets, out, valid=item.valid), si, gen
-                )
+                mb = MicroBatch(item.tickets, out, valid=item.valid, batch=item.batch)
+                with span(names.handoff, batch=item.batch):
+                    ok = self._forward(qs[si], mb, si, gen)
                 self._clear_processing(si, gen)
                 if not ok:
                     return
@@ -1001,22 +1023,28 @@ class PipelineServer:
                 item = self._qs[-1].get()
                 if item is _SENTINEL:
                     return
-                (out,) = item.env.values()  # last stage prunes to the output
-                now = time.perf_counter()
-                for ticket, row in zip(item.tickets, split_rows(out, item.valid)):
-                    if ticket.done():
-                        # At-least-once re-dispatch raced a stalled worker's
-                        # late result: the ticket already resolved with an
-                        # identical row (stage fns are pure) — suppress the
-                        # duplicate so clients see each output exactly once.
-                        self.metrics.recovery.note_duplicate()
+                with span(EGRESS, batch=item.batch):
+                    (out,) = item.env.values()  # last stage prunes to the output
+                    now = time.perf_counter()
+                    rows = split_rows(out, item.valid)
+                for ticket, row in zip(item.tickets, rows):
+                    # the client's done-callbacks run in here, so their time
+                    # is the resolve span's, not egress's
+                    with span(RESOLVE, ticket=ticket.id, batch=item.batch):
+                        if ticket.done():
+                            # At-least-once re-dispatch raced a stalled
+                            # worker's late result: the ticket already
+                            # resolved with an identical row (stage fns are
+                            # pure) — suppress the duplicate so clients see
+                            # each output exactly once.
+                            self.metrics.recovery.note_duplicate()
+                            with self._lock:
+                                self._inflight.discard(ticket)
+                            continue
+                        self.metrics.note_complete(ticket.submitted_at, now)
                         with self._lock:
                             self._inflight.discard(ticket)
-                        continue
-                    self.metrics.note_complete(ticket.submitted_at, now)
-                    with self._lock:
-                        self._inflight.discard(ticket)
-                    ticket._resolve(row)
+                        ticket._resolve(row)
         except BaseException as e:
             self._fail(e)
 
