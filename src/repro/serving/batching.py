@@ -28,6 +28,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Env = Dict[str, jnp.ndarray]
 
@@ -67,10 +68,17 @@ def stack_envs(envs: Sequence[Env], pad_to: Optional[int] = None) -> Env:
     return out
 
 
-def split_rows(x: jnp.ndarray, valid: int) -> List[jnp.ndarray]:
+def split_rows(x: jnp.ndarray, valid: int) -> List[np.ndarray]:
     """The first ``valid`` rows of a batched output, one array per image
-    (keeping the leading batch dim of 1, matching per-image execution)."""
-    return [x[i : i + 1] for i in range(valid)]
+    (keeping the leading batch dim of 1, matching per-image execution).
+
+    The batch reaches the host in one copy, padded rows included, and each
+    row is a read-only view of that host array: one device-to-host
+    transfer a micro-batch, not ``valid`` dispatched device slices, with
+    the same bits and dtype as ``x[i:i+1]``."""
+    rows = np.asarray(x)[:valid, None]
+    rows.flags.writeable = False
+    return list(rows)
 
 
 def gather(

@@ -61,7 +61,8 @@ ADMIT = "serve.admit"
 GATHER = "serve.gather"
 #: stage 0: concatenating and padding the images (batch, n: valid rows)
 STACK = "serve.stack"
-#: the egress worker: splitting the last stage's output into rows (batch)
+#: the egress worker: copying the last stage's output to the host in one
+#: transfer and splitting it into rows (batch)
 EGRESS = "serve.egress"
 #: the egress worker, per ticket: its completion stamp, waking its client
 #: and running the client's done-callbacks (ticket, batch: which
@@ -296,6 +297,10 @@ class ServerMetrics:
         self._e2e_s: Deque[float] = collections.deque(maxlen=E2E_WINDOW)
         self._queue_wait_s: Deque[float] = collections.deque(maxlen=E2E_WINDOW)
         self._completed = 0
+        # Egress: device-to-host copies (one a micro-batch) and the valid
+        # rows they carried to tickets, duplicates included.
+        self._egress_copies = 0
+        self._egress_rows = 0
         self._first_submit: Optional[float] = None
         self._last_complete: Optional[float] = None
 
@@ -323,6 +328,13 @@ class ServerMetrics:
         with self._lock:
             self._queue_wait_s.append(now - submitted_at)
 
+    def note_egress(self, rows: int) -> None:
+        """Record one device-to-host copy at egress carrying ``rows`` valid
+        rows."""
+        with self._lock:
+            self._egress_copies += 1
+            self._egress_rows += rows
+
     def note_complete(self, submitted_at: float, now: float) -> None:
         with self._lock:
             self._e2e_s.append(now - submitted_at)
@@ -347,8 +359,12 @@ class ServerMetrics:
             e2e = list(self._e2e_s)
             qwait = list(self._queue_wait_s)
             completed = self._completed
+            egress_copies = self._egress_copies
+            egress_rows = self._egress_rows
         return {
             "completed": completed,
+            "egress_copies": egress_copies,
+            "egress_rows": egress_rows,
             "epoch": self.epoch,
             "throughput_img_s": self.throughput(),
             "e2e_p50_s": percentile(e2e, 50),

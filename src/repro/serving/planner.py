@@ -352,9 +352,13 @@ def serve(
     ``fairness`` ("sum" | "max-min") selects the partition objective —
     both are multi-model-only and rejected for a single model.
 
+    A ticket's result is a read-only ``numpy.ndarray`` of shape
+    ``(1, classes)``: a row of the one device-to-host copy the server
+    makes of each micro-batch.
+
     >>> server = serve("squeezenet", mode="best", batch_size=8)
     >>> ticket = server.submit(image)
-    >>> logits = ticket.result()
+    >>> logits = ticket.result()  # numpy, (1, classes), read-only
     >>> server.stop()
 
     >>> mm = serve({"alex": "alexnet", "squeeze": "squeezenet"})

@@ -92,12 +92,12 @@ class Ticket:
         self.submitted_at = submitted_at
         self.dequeued_at: Optional[float] = None
         self._event = threading.Event()
-        self._value: Optional[jnp.ndarray] = None
+        self._value: Optional[np.ndarray] = None
         self._error: Optional[BaseException] = None
         self._callbacks: List = []
         self._cb_lock = threading.Lock()
 
-    def _resolve(self, value: jnp.ndarray) -> None:
+    def _resolve(self, value: np.ndarray) -> None:
         self._value = value
         self._finish()
 
@@ -140,7 +140,11 @@ class Ticket:
     def done(self) -> bool:
         return self._event.is_set()
 
-    def result(self, timeout: Optional[float] = None) -> jnp.ndarray:
+    def result(self, timeout: Optional[float] = None) -> np.ndarray:
+        """The image's output, shape ``(1, ...)``, waiting up to
+        ``timeout`` seconds.  It is a read-only host array: a row of the
+        one device-to-host copy egress makes of each micro-batch
+        (:func:`~repro.serving.batching.split_rows`)."""
         if not self._event.wait(timeout):
             raise TimeoutError("result not ready")
         if self._error is not None:
@@ -1027,6 +1031,7 @@ class PipelineServer:
                     (out,) = item.env.values()  # last stage prunes to the output
                     now = time.perf_counter()
                     rows = split_rows(out, item.valid)
+                    self.metrics.note_egress(len(rows))
                 for ticket, row in zip(item.tickets, rows):
                     # the client's done-callbacks run in here, so their time
                     # is the resolve span's, not egress's
