@@ -75,7 +75,7 @@ def _route_records(model, tuner):
             # every backend is timed on identical total work
             fn = jax.jit(
                 lambda x, w, b, kb=kb, d=d: finish_act(
-                    kb.conv2d(d.name, x, w, b, stride=d.stride, pad=d.pad, relu=True)
+                    kb.conv2d(d.name, x, w, b, stride=d.stride, pad=d.pad, act="relu")
                 )
             )
             t = _best_of(fn, x, w, b)
@@ -118,7 +118,7 @@ def _interpret_sweep_records():
         for cfg in cands:
             timed[cfg] = _best_of(
                 lambda cfg=cfg: conv2d_fused(
-                    x, w, b, stride=d.stride, pad=d.pad, relu=True,
+                    x, w, b, stride=d.stride, pad=d.pad, act="relu",
                     interpret=True, **cfg.as_kwargs(),
                 )
             )

@@ -2,9 +2,10 @@
 
 Each network is a topologically-ordered list of nodes.  Weighted nodes
 (conv / depthwise / fc) are the paper's *major layers*; every other node
-(pool, LRN, concat, add, ...) is attached to the preceding major layer for
-scheduling purposes (paper §III-B: "all kernels from the non-convolutional
-layers are considered part of the previous convolutional layer").
+(pool, LRN, LayerNorm, concat, add, ...) is attached to the preceding major
+layer for scheduling purposes (paper §III-B: "all kernels from the
+non-convolutional layers are considered part of the previous convolutional
+layer").
 
 The graph supports executing an arbitrary contiguous node range against an
 environment of live tensors — exactly what a pipeline stage needs.
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.descriptors import ConvDescriptor
+from ..kernels.conv_fused import apply_act
 from . import layers as L
 
 MAJOR_KINDS = ("conv", "depthwise", "fc")
@@ -69,6 +71,11 @@ class Graph:
     def lrn(self, name, src):
         return self.add("lrn", name, [src])
 
+    def layernorm(self, name, src, eps=1e-6):
+        """Normalisation over the channel axis, no affine (ConvNeXt folds
+        it into the next linear layer)."""
+        return self.add("layernorm", name, [src], eps=eps)
+
     def concat(self, name, srcs):
         return self.add("concat", name, list(srcs))
 
@@ -107,7 +114,7 @@ class Graph:
                 shapes[n.name] = (oh, ow, c)
             elif n.kind == "gap":
                 shapes[n.name] = (s[-1],)
-            elif n.kind in ("lrn", "softmax"):
+            elif n.kind in ("lrn", "layernorm", "softmax"):
                 shapes[n.name] = s
             elif n.kind == "concat":
                 shapes[n.name] = (*s[:-1], sum(i[-1] for i in ins))
@@ -203,19 +210,20 @@ class Graph:
         """Execute one node.  ``backend`` (a resolved
         :class:`repro.kernels.backend.KernelBackend`) routes the major
         layers through the selected kernel backend and may fuse the
-        node's ReLU into the kernel epilogue; ``gemm_fn`` is the legacy
-        injection point (quantized closures, tests) and wins when set."""
+        node's activation (``act``: none, relu or gelu) into the kernel
+        epilogue; ``gemm_fn`` is the legacy injection point (quantized
+        closures, tests) and wins when set."""
         ins = [env[i] for i in n.inputs]
         x = ins[0]
         act_done = False
-        relu = n.attrs.get("act") == "relu"
+        act = n.attrs.get("act", "none")
         if n.kind == "conv":
             p = params[n.name]
             if backend is not None and gemm_fn is None:
                 y, act_done = backend.conv2d(
                     n.name, x, p["w"], p["b"], stride=n.attrs["stride"],
                     pad=n.attrs["pad"], groups=n.attrs.get("groups", 1),
-                    relu=relu,
+                    act=act,
                 )
             else:
                 y = L.conv2d(
@@ -227,14 +235,14 @@ class Graph:
             if backend is not None and gemm_fn is None:
                 y, act_done = backend.depthwise(
                     n.name, x, p["w"], p["b"], stride=n.attrs["stride"],
-                    pad=n.attrs["pad"], relu=relu,
+                    pad=n.attrs["pad"], act=act,
                 )
             else:
                 y = L.depthwise_conv2d(x, p["w"], p["b"], stride=n.attrs["stride"], pad=n.attrs["pad"])
         elif n.kind == "fc":
             p = params[n.name]
             if backend is not None and gemm_fn is None:
-                y, act_done = backend.dense(n.name, x, p["w"], p["b"], relu=relu)
+                y, act_done = backend.dense(n.name, x, p["w"], p["b"], act=act)
             else:
                 y = L.dense(x, p["w"], p["b"], gemm_fn=gemm_fn)
         elif n.kind == "pool_max":
@@ -245,6 +253,8 @@ class Graph:
             y = L.global_avg_pool(x)
         elif n.kind == "lrn":
             y = L.lrn(x)
+        elif n.kind == "layernorm":
+            y = L.layer_norm(x, n.attrs["eps"])
         elif n.kind == "concat":
             y = jnp.concatenate(ins, axis=-1)
         elif n.kind == "add":
@@ -255,8 +265,8 @@ class Graph:
             y = x[..., n.attrs["lo"] : n.attrs["hi"]]
         else:
             raise ValueError(n.kind)
-        if relu and not act_done:
-            y = L.relu(y)
+        if not act_done:
+            y = apply_act(y, act)
         return y
 
     def apply_range(

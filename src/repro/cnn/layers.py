@@ -156,6 +156,14 @@ def lrn(x: jnp.ndarray, size: int = 5, alpha: float = 1e-4, beta: float = 0.75, 
     return x / jnp.power(k + alpha * acc, beta)
 
 
+def layer_norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    """Normalisation over the last (channel) axis, without an affine:
+    NHWC maps and pooled ``[B, C]`` vectors alike."""
+    mu = x.mean(axis=-1, keepdims=True)
+    var = jnp.square(x - mu).mean(axis=-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps)
+
+
 def relu(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.maximum(x, 0.0)
 

@@ -6,6 +6,11 @@ match their ARM-CL implementations exactly:
     MobileNet   28 (14 conv + 13 depthwise + 1 fc)
     ResNet50    54 (1 conv + 52 block convs + 1 fc)
     SqueezeNet  26 (2 conv + 8 fire x 3 conv)
+
+and two beyond Table I:
+
+    VGG-16      16 (13 conv + 3 fc)
+    ConvNeXt-T  59 (1 stem + 3 downsample convs + 18 blocks x 3 + 1 fc)
 """
 from __future__ import annotations
 
@@ -166,8 +171,45 @@ def vgg16() -> Graph:
     return g
 
 
+def convnext(depths=(3, 3, 9, 3), dims=(96, 192, 384, 768), *, classes=1000,
+             input_shape=(224, 224, 3), name="convnext") -> Graph:
+    """ConvNeXt (arXiv:2201.03545): a 4x4/4 patchify stem and LayerNorm,
+    then stages of blocks, each ``depthwise 7x7 -> LayerNorm -> 1x1 to
+    4C -> GELU -> 1x1 back to C -> residual add``, with
+    ``LayerNorm -> 2x2/2 conv`` between stages; global average pool,
+    LayerNorm and fc.  The LayerNorms carry no affine and layer scale is
+    not a node: both fold into the weights of the linear layer after them
+    (the stem's LayerNorm keeps the identity affine it starts from).
+    Every LayerNorm's eps is 1e-6, as in each of the paper's variants."""
+    g = Graph(name, tuple(input_shape))
+    x = g.conv("stem", "input", dims[0], 4, stride=4, pad=0, act="none")
+    x = g.layernorm("stem_ln", x)
+    for si, (depth, dim) in enumerate(zip(depths, dims), start=1):
+        if si > 1:
+            x = g.layernorm(f"ds{si}_ln", x)
+            x = g.conv(f"ds{si}", x, dim, 2, stride=2, pad=0, act="none")
+        for bi in range(1, depth + 1):
+            tag = f"s{si}b{bi}"
+            y = g.depthwise(f"{tag}_dw", x, 7, act="none")
+            y = g.layernorm(f"{tag}_ln", y)
+            y = g.conv(f"{tag}_pw1", y, 4 * dim, 1, act="gelu")
+            y = g.conv(f"{tag}_pw2", y, dim, 1, act="none")
+            x = g.residual_add(f"{tag}_add", y, x, act="none")
+    x = g.gap("gap", x)
+    x = g.layernorm("head_ln", x)
+    fc = g.fc("fc", x, classes)
+    g.softmax("prob", fc)
+    return g
+
+
+def convnext_tiny() -> Graph:
+    """ConvNeXt-T: depths (3, 3, 9, 3), widths (96, 192, 384, 768)."""
+    return convnext(name="convnext_tiny")
+
+
 MODELS: Dict[str, Callable[[], Graph]] = {
     "alexnet": alexnet,
+    "convnext_tiny": convnext_tiny,
     "googlenet": googlenet,
     "mobilenet": mobilenet,
     "resnet50": resnet50,
@@ -176,9 +218,10 @@ MODELS: Dict[str, Callable[[], Graph]] = {
 }
 
 # Paper Table I major-node counts, used as a structural regression test
-# (vgg16 is beyond Table I: 13 conv + 3 fc).
+# (vgg16 and convnext_tiny are beyond Table I; see the module docstring).
 PAPER_MAJOR_COUNTS = {
     "alexnet": 11,
+    "convnext_tiny": 59,
     "googlenet": 58,
     "mobilenet": 28,
     "resnet50": 54,

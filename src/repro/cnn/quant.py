@@ -72,7 +72,7 @@ def make_quant_gemm_fn(qparams_entry):
 
 
 def make_quant_conv_fn(qparams_entry, *, stride: int = 1, pad: int = 0,
-                       relu: bool = False, pallas: bool = False):
+                       act: str = "none", pallas: bool = False):
     """The fused-conv counterpart of :func:`make_quant_gemm_fn`: a closure
     ``x -> y`` executing one quantized conv layer with the requant step
     fused into the kernel epilogue (`kernels/conv_fused.py`).
@@ -85,4 +85,4 @@ def make_quant_conv_fn(qparams_entry, *, stride: int = 1, pad: int = 0,
     qw, s, z = qparams_entry["qw"], qparams_entry["scale"], qparams_entry["zp"]
     b, shape = qparams_entry["b"], tuple(qparams_entry["shape"])
     fn = qconv2d_fused if pallas else qfused_route_ref
-    return lambda x: fn(x, qw, s, z, b, shape, stride=stride, pad=pad, relu=relu)
+    return lambda x: fn(x, qw, s, z, b, shape, stride=stride, pad=pad, act=act)

@@ -291,7 +291,7 @@ class ConvAutotuner:
                 t = _best_of_k(
                     lambda: conv2d_fused(
                         x, wgt, bias, stride=desc.stride, pad=desc.pad,
-                        relu=True, **cfg.as_kwargs(),
+                        act="relu", **cfg.as_kwargs(),
                     ).block_until_ready(),
                     self.repeats,
                 )

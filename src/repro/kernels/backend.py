@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 from .autotune import ConvAutotuner
 from .config import interpret_requested, on_tpu
-from .conv_fused import conv2d_fused, fused_route_ref, matmul_fused, supports
+from .conv_fused import apply_act, conv2d_fused, fused_route_ref, matmul_fused, supports
 
 BACKENDS = ("xla", "pallas", "pallas_fused")
 
@@ -110,10 +110,11 @@ class KernelBackend:
         stride: int = 1,
         pad: int = 0,
         groups: int = 1,
-        relu: bool = False,
+        act: str = "none",
     ) -> Tuple[jnp.ndarray, bool]:
         """Returns ``(y, act_done)`` — ``act_done`` when the backend fused
-        the ReLU into the kernel epilogue."""
+        the activation ``act`` ("none", "relu" or "gelu") into the kernel
+        epilogue."""
         from ..cnn import layers as L
 
         choice = self.for_node(name)
@@ -134,19 +135,19 @@ class KernelBackend:
             self.fallbacks[name] = f"groups={groups}"
             return (
                 fused_route_ref(
-                    x, w, b, stride=stride, pad=pad, groups=groups, relu=relu
+                    x, w, b, stride=stride, pad=pad, groups=groups, act=act
                 ),
                 True,
             )
         if not _pallas_active(self.interpret):
             # fused XLA lowering of the same operation (off-TPU serving)
             return (
-                fused_route_ref(x, w, b, stride=stride, pad=pad, relu=relu),
+                fused_route_ref(x, w, b, stride=stride, pad=pad, act=act),
                 True,
             )
         desc = self._desc(name, x, w, stride, pad, groups)
         y = conv2d_fused(
-            x, w, b, stride=stride, pad=pad, relu=relu,
+            x, w, b, stride=stride, pad=pad, act=act,
             interpret=self.interpret, **self._blocks(desc),
         )
         return y, True
@@ -160,7 +161,7 @@ class KernelBackend:
         *,
         stride: int = 1,
         pad: int = 0,
-        relu: bool = False,
+        act: str = "none",
     ) -> Tuple[jnp.ndarray, bool]:
         """Depthwise convs keep their native grouped-conv implementation on
         every backend (ARM-CL special-cases them the same way); under
@@ -174,7 +175,7 @@ class KernelBackend:
             return (
                 fused_route_ref(
                     x, w, b, stride=stride, pad=pad,
-                    groups=x.shape[-1], relu=relu,
+                    groups=x.shape[-1], act=act,
                 ),
                 True,
             )
@@ -188,7 +189,7 @@ class KernelBackend:
         w: jnp.ndarray,
         b: Optional[jnp.ndarray],
         *,
-        relu: bool = False,
+        act: str = "none",
     ) -> Tuple[jnp.ndarray, bool]:
         from ..cnn import layers as L
 
@@ -203,12 +204,10 @@ class KernelBackend:
         x2 = x.reshape(x.shape[0], -1)
         bias = jnp.zeros((w.shape[1],), jnp.float32) if b is None else b
         if not _pallas_active(self.interpret):
-            y = x2 @ w + bias  # XLA fuses epilogue into the GEMM
-            if relu:
-                y = jnp.maximum(y, 0.0)
-            return y, True
+            # XLA fuses the epilogue into the GEMM
+            return apply_act(x2 @ w + bias, act), True
         return (
-            matmul_fused(x2, w, bias, relu=relu, interpret=self.interpret),
+            matmul_fused(x2, w, bias, act=act, interpret=self.interpret),
             True,
         )
 
@@ -275,7 +274,7 @@ def measure_graph_routes(
             timed(
                 desc,
                 lambda x=x, w=w, b=b, n=desc.name: finish_act(
-                    kb.dense(n, x, w, b, relu=True)
+                    kb.dense(n, x, w, b, act="relu")
                 ),
             )
         elif desc.kind == "depthwise":
@@ -289,7 +288,7 @@ def measure_graph_routes(
             timed(
                 desc,
                 lambda x=x, w=w, b=b, d=desc: finish_act(
-                    kb.depthwise(d.name, x, w, b, stride=d.stride, pad=d.pad, relu=True)
+                    kb.depthwise(d.name, x, w, b, stride=d.stride, pad=d.pad, act="relu")
                 ),
             )
         else:
@@ -306,7 +305,7 @@ def measure_graph_routes(
                 lambda x=x, w=w, b=b, d=desc: finish_act(
                     kb.conv2d(
                         d.name, x, w, b, stride=d.stride, pad=d.pad,
-                        groups=d.groups, relu=True,
+                        groups=d.groups, act="relu",
                     )
                 ),
             )

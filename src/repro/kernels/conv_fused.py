@@ -6,9 +6,9 @@ GEMM reads it back — for a 3x3 conv that is a 9x write+read amplification
 of the input tensor.  This kernel is the *implicit* formulation: each
 GEMM grid step forms its patch block in VMEM from one padded input row
 and contracts it immediately, so the patch matrix never exists in HBM,
-and the epilogue — bias add, ReLU, and the QASYMM8 requant scale of
-`cnn/quant.py` — runs inside the K-flush of the accumulator instead of
-as separate HBM round trips.
+and the epilogue — bias add, the activation (ReLU or exact GELU), and
+the QASYMM8 requant scale of `cnn/quant.py` — runs inside the K-flush of
+the accumulator instead of as separate HBM round trips.
 
 Grid: ``(B, Oh, Ow/bm, Cout/bn, Fh * C/bk)`` with the fused K dimension
 (filter row x channel block) innermost so the f32/i32 accumulator tile
@@ -44,10 +44,51 @@ from jax.experimental.pallas import tpu as pltpu
 from .config import default_interpret
 
 
+ACTS = ("none", "relu", "gelu")
+
+# float32 erf as x * P(x^2) / Q(x^2) on [-4, 4] (outside it erf is +-1 in
+# float32), with the coefficients of Eigen's float32 erf.  Mosaic has no
+# lowering for lax.erf_p; this form needs only mul, add and one divide,
+# and stays within 5 float32 ulps of 1.0 of lax.erf (tests/test_convnext.py).
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04, -1.68282697438203e-03,
+          -7.37332916720468e-03, -1.42647390514189e-02)
+
+
+def erf_f32(x: jnp.ndarray) -> jnp.ndarray:
+    """erf of a float32 array from mul, add and divide alone."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p, q = _ERF_P[0], _ERF_Q[0]
+    for c in _ERF_P[1:]:
+        p = p * x2 + c
+    for c in _ERF_Q[1:]:
+        q = q * x2 + c
+    return x * p / q
+
+
+def apply_act(y: jnp.ndarray, act: str, *, in_kernel: bool = False) -> jnp.ndarray:
+    """A node's activation, in a kernel's epilogue or after an XLA op:
+    ``"relu"``, exact (erf) ``"gelu"``, or ``"none"``.  Inside a Pallas
+    body (``in_kernel``) GELU's erf is :func:`erf_f32`; elsewhere XLA
+    lowers ``jax.nn.gelu`` itself."""
+    if act == "relu":
+        return jnp.maximum(y, 0.0)
+    if act == "gelu":
+        if in_kernel:
+            return 0.5 * y * (1.0 + erf_f32(y * 0.7071067811865476))
+        return jax.nn.gelu(y, approximate=False)
+    if act != "none":
+        raise ValueError(f"act must be one of {ACTS}, got {act!r}")
+    return y
+
+
 # --------------------------------------------------------------- kernel body
 def _conv_fused_kernel(
     x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref,
-    *, fw: int, stride: int, bm: int, n_m: int, ext: int, n_k: int, relu: bool,
+    *, fw: int, stride: int, bm: int, n_m: int, ext: int, n_k: int, act: str,
 ):
     k = pl.program_id(4)
 
@@ -80,9 +121,7 @@ def _conv_fused_kernel(
     @pl.when(k == n_k - 1)
     def _flush():
         y = acc_ref[...].astype(jnp.float32) * s_ref[0] + b_ref[0]
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        o_ref[0, 0] = y.astype(o_ref.dtype)
+        o_ref[0, 0] = apply_act(y, act, in_kernel=True).astype(o_ref.dtype)
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, to: int) -> jnp.ndarray:
@@ -107,7 +146,7 @@ def default_blocks(ow: int, cout: int, cin: int) -> Tuple[int, int, int]:
     jax.jit,
     static_argnames=(
         "fh", "fw", "stride", "block_m", "block_n", "block_k",
-        "relu", "interpret", "out_dtype",
+        "act", "interpret", "out_dtype",
     ),
 )
 def _conv_fused_call(
@@ -118,7 +157,7 @@ def _conv_fused_call(
     *,
     fh: int, fw: int, stride: int,
     block_m: int, block_n: int, block_k: int,
-    relu: bool, interpret: bool, out_dtype,
+    act: str, interpret: bool, out_dtype,
 ) -> jnp.ndarray:
     b, hp, wp, c = xp.shape
     cout = w4.shape[-1]
@@ -146,7 +185,7 @@ def _conv_fused_call(
         functools.partial(
             _conv_fused_kernel,
             fw=fw, stride=stride, bm=bm, n_m=n_m, ext=ext, n_k=n_k,
-            relu=relu,
+            act=act,
         ),
         grid=(b, oh, n_m, n_n, n_k),
         in_specs=[
@@ -188,13 +227,14 @@ def conv2d_fused(
     *,
     stride: int = 1,
     pad: int = 0,
-    relu: bool = False,
+    act: str = "none",
     block_m: Optional[int] = None,
     block_n: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Fused conv + bias + ReLU via the implicit-GEMM Pallas kernel.
+    """Fused conv + bias + activation (``act`` in :data:`ACTS`) via the
+    implicit-GEMM Pallas kernel.
 
     ``interpret=None`` resolves by platform (kernels/config.py).  Shapes
     the kernel cannot tile must be routed by the caller (backend.py) to
@@ -210,7 +250,7 @@ def conv2d_fused(
         xp, w, jnp.ones((cout,), jnp.float32), bias,
         fh=fh, fw=fw, stride=stride,
         block_m=block_m or dm, block_n=block_n or dn, block_k=block_k or dk,
-        relu=relu, interpret=default_interpret(interpret), out_dtype=x.dtype,
+        act=act, interpret=default_interpret(interpret), out_dtype=x.dtype,
     )
 
 
@@ -224,7 +264,7 @@ def qconv2d_fused(
     *,
     stride: int = 1,
     pad: int = 0,
-    relu: bool = False,
+    act: str = "none",
     block_m: Optional[int] = None,
     block_n: Optional[int] = None,
     block_k: Optional[int] = None,
@@ -236,7 +276,7 @@ def qconv2d_fused(
     the whole batch, as the patch-matrix route does), both operands shift
     to the zero-point-free int32 domain, the kernel accumulates in int32,
     and the epilogue applies the merged requant scale ``sa * scale[j]``
-    plus bias (and ReLU) before the single f32 write to HBM.
+    plus bias (and the activation) before the single f32 write to HBM.
     """
     from ..cnn.quant import quantize_tensor
 
@@ -256,12 +296,12 @@ def qconv2d_fused(
         xqp, wq4, merged, bias,
         fh=fh, fw=fw, stride=stride,
         block_m=block_m or dm, block_n=block_n or dn, block_k=block_k or dk,
-        relu=relu, interpret=default_interpret(interpret), out_dtype=jnp.float32,
+        act=act, interpret=default_interpret(interpret), out_dtype=jnp.float32,
     )
 
 
 # ----------------------------------------------------- fused dense (fc) GEMM
-def _matmul_fused_kernel(a_ref, b_ref, s_ref, c_ref, o_ref, acc_ref, *, n_k, relu):
+def _matmul_fused_kernel(a_ref, b_ref, s_ref, c_ref, o_ref, acc_ref, *, n_k, act):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
@@ -273,14 +313,12 @@ def _matmul_fused_kernel(a_ref, b_ref, s_ref, c_ref, o_ref, acc_ref, *, n_k, rel
     @pl.when(k_step == n_k - 1)
     def _flush():
         y = acc_ref[...].astype(jnp.float32) * s_ref[0] + c_ref[0]
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        o_ref[...] = y.astype(o_ref.dtype)
+        o_ref[...] = apply_act(y, act, in_kernel=True).astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_m", "block_n", "block_k", "relu", "interpret"),
+    static_argnames=("block_m", "block_n", "block_k", "act", "interpret"),
 )
 def matmul_fused(
     a: jnp.ndarray,  # [M, K]
@@ -290,10 +328,10 @@ def matmul_fused(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    relu: bool = False,
+    act: str = "none",
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """GEMM with the dense layer's epilogue (bias, ReLU) in the K-flush —
+    """GEMM with the dense layer's epilogue (bias, activation) in the K-flush —
     the fc-node counterpart of the fused conv kernel."""
     interpret = default_interpret(interpret)
     m, k = a.shape
@@ -306,7 +344,7 @@ def matmul_fused(
     n_k = a_p.shape[1] // bk
     grid = (a_p.shape[0] // bm, w_p.shape[1] // bn, n_k)
     out = pl.pallas_call(
-        functools.partial(_matmul_fused_kernel, n_k=n_k, relu=relu),
+        functools.partial(_matmul_fused_kernel, n_k=n_k, act=act),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -331,7 +369,7 @@ def fused_route_ref(
     stride: int = 1,
     pad: int = 0,
     groups: int = 1,
-    relu: bool = False,
+    act: str = "none",
 ) -> jnp.ndarray:
     """The fused route's XLA lowering: direct convolution + fused epilogue.
 
@@ -353,9 +391,7 @@ def fused_route_ref(
         y = y.reshape(bsz, oh, ow, -1)
         if b is not None:
             y = y + b
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        return y
+        return apply_act(y, act)
     y = jax.lax.conv_general_dilated(
         x, w, (stride, stride), [(pad, pad), (pad, pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
@@ -363,9 +399,7 @@ def fused_route_ref(
     )
     if b is not None:
         y = y + b
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    return y
+    return apply_act(y, act)
 
 
 def qfused_route_ref(
@@ -378,7 +412,7 @@ def qfused_route_ref(
     *,
     stride: int = 1,
     pad: int = 0,
-    relu: bool = False,
+    act: str = "none",
 ) -> jnp.ndarray:
     """XLA lowering of :func:`qconv2d_fused`: the same per-tensor activation
     quantization, int32 direct convolution in the zero-point-free domain,
@@ -397,6 +431,4 @@ def qfused_route_ref(
     y = acc.astype(jnp.float32) * (sa * scale).reshape(1, 1, 1, -1)
     if b is not None:
         y = y + b
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    return y
+    return apply_act(y, act)

@@ -67,7 +67,7 @@ def test_conv_fused_kernel_matches_oracle(hw, c, k, cout, stride, pad, bm, bn, b
     w = _arr((k, k, c, cout))
     b = _arr((cout,))
     got = conv2d_fused(
-        x, w, b, stride=stride, pad=pad, relu=True,
+        x, w, b, stride=stride, pad=pad, act="relu",
         block_m=bm, block_n=bn, block_k=bk, interpret=True,
     )
     want = _conv_oracle(x, w, b, stride, pad, relu=True)
@@ -84,7 +84,7 @@ def test_conv_fused_blocking_invariance():
 
 def test_matmul_fused_matches_oracle():
     a, w, b = _arr((5, 70)), _arr((70, 33)), _arr((33,))
-    got = matmul_fused(a, w, b, block_m=4, block_n=16, block_k=32, relu=True, interpret=True)
+    got = matmul_fused(a, w, b, block_m=4, block_n=16, block_k=32, act="relu", interpret=True)
     want = jnp.maximum(a @ w + b, 0.0)
     np.testing.assert_allclose(got, want, rtol=RTOL, atol=ATOL)
 
@@ -114,12 +114,12 @@ def test_make_quant_conv_fn_routes_match():
     w = _arr((3, 3, 4, 6))
     b = _arr((6,))
     qp = quantize_graph_params({"l": {"w": w, "b": b}})["l"]
-    xla_fn = make_quant_conv_fn(qp, stride=1, pad=1, relu=True)
+    xla_fn = make_quant_conv_fn(qp, stride=1, pad=1, act="relu")
     np.testing.assert_allclose(
         xla_fn(x),
         qconv2d_fused(
             x, qp["qw"], qp["scale"], qp["zp"], b, (3, 3, 4, 6),
-            stride=1, pad=1, relu=True, interpret=True,
+            stride=1, pad=1, act="relu", interpret=True,
         ),
         rtol=RTOL, atol=ATOL,
     )
@@ -158,7 +158,7 @@ def test_backend_grouped_conv_fallback_parity(groups, stride, pad):
     b = _arr((cout,))
     kb = resolve_backend("pallas_fused")
     y, act_done = kb.conv2d(
-        "g", x, w, b, stride=stride, pad=pad, groups=groups, relu=True
+        "g", x, w, b, stride=stride, pad=pad, groups=groups, act="relu"
     )
     assert act_done  # the fallback still fuses the epilogue
     assert "g" in kb.fallbacks and "groups" in kb.fallbacks["g"]
@@ -173,7 +173,7 @@ def test_backend_depthwise_fallback_parity(stride, pad):
     w = _arr((3, 3, 1, c))
     b = _arr((c,))
     kb = resolve_backend("pallas_fused")
-    y, act_done = kb.depthwise("dw", x, w, b, stride=stride, pad=pad, relu=True)
+    y, act_done = kb.depthwise("dw", x, w, b, stride=stride, pad=pad, act="relu")
     assert act_done and kb.fallbacks["dw"] == "depthwise"
     want = _conv_oracle(x, w, b, stride, pad, groups=c, relu=True)
     np.testing.assert_allclose(y, want, rtol=RTOL, atol=ATOL)
@@ -206,7 +206,7 @@ def test_interpret_override_raises_on_tpu(monkeypatch, value):
         default_interpret(None)
     x, w, b = _arr((1, 6, 6, 2)), _arr((3, 3, 2, 4)), _arr((4,))
     with pytest.raises(RuntimeError, match="REPRO_PALLAS_INTERPRET"):
-        resolve_backend("pallas_fused").conv2d("c", x, w, b, pad=1, relu=True)
+        resolve_backend("pallas_fused").conv2d("c", x, w, b, pad=1, act="relu")
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
     assert default_interpret(None) is False  # compiled, as on any TPU run
 

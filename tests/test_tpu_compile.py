@@ -46,10 +46,10 @@ def _spec(shape, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
 
-def _compile_conv(one_chip, hw, cin, k, cout, stride, pad, **blocks):
+def _compile_conv(one_chip, hw, cin, k, cout, stride, pad, act="relu", **blocks):
     def f(x, w, b):
         return conv2d_fused(
-            x, w, b, stride=stride, pad=pad, relu=True, interpret=False, **blocks
+            x, w, b, stride=stride, pad=pad, act=act, interpret=False, **blocks
         )
 
     return jax.jit(f).lower(
@@ -60,21 +60,38 @@ def _compile_conv(one_chip, hw, cin, k, cout, stride, pad, **blocks):
 
 
 @pytest.mark.parametrize(
-    "hw,cin,k,cout,stride,pad",
+    "hw,cin,k,cout,stride,pad,act",
     [
-        (224, 3, 3, 64, 1, 1),     # VGG-16 conv1_1 (two column tiles)
-        (56, 256, 3, 256, 1, 1),   # VGG-16 conv3_2
-        (14, 512, 3, 512, 1, 1),   # VGG-16 conv5_2
-        (56, 256, 1, 512, 2, 0),   # ResNet-50 res3a_proj, 1x1 stride 2
-        (224, 3, 7, 64, 2, 3),     # ResNet-50 conv1, 7x7 stride 2
-        (227, 3, 11, 96, 4, 0),    # AlexNet conv1, 11x11 stride 4
+        (224, 3, 3, 64, 1, 1, "relu"),     # VGG-16 conv1_1 (two column tiles)
+        (56, 256, 3, 256, 1, 1, "relu"),   # VGG-16 conv3_2
+        (14, 512, 3, 512, 1, 1, "relu"),   # VGG-16 conv5_2
+        (56, 256, 1, 512, 2, 0, "relu"),   # ResNet-50 res3a_proj, 1x1 stride 2
+        (224, 3, 7, 64, 2, 3, "relu"),     # ResNet-50 conv1, 7x7 stride 2
+        (227, 3, 11, 96, 4, 0, "relu"),    # AlexNet conv1, 11x11 stride 4
+        (224, 3, 4, 96, 4, 0, "none"),     # ConvNeXt-T stem, 4x4 stride 4
+        (56, 96, 2, 192, 2, 0, "none"),    # ConvNeXt-T ds2, 2x2 stride 2
+        (56, 96, 1, 384, 1, 0, "gelu"),    # ConvNeXt-T s1 pw1, erf in the epilogue
     ],
     ids=["vgg16-conv1_1", "vgg16-conv3_2", "vgg16-conv5_2",
-         "resnet50-res3a_proj", "resnet50-conv1", "alexnet-conv1"],
+         "resnet50-res3a_proj", "resnet50-conv1", "alexnet-conv1",
+         "convnext_tiny-stem", "convnext_tiny-ds2", "convnext_tiny-s1_pw1"],
 )
-def test_conv_fused_compiles(one_chip, hw, cin, k, cout, stride, pad):
-    compiled = _compile_conv(one_chip, hw, cin, k, cout, stride, pad)
+def test_conv_fused_compiles(one_chip, hw, cin, k, cout, stride, pad, act):
+    compiled = _compile_conv(one_chip, hw, cin, k, cout, stride, pad, act=act)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "hw,cin,k,cout,stride",
+    [(224, 3, 4, 96, 4), (56, 96, 2, 192, 2)],
+    ids=["convnext_tiny-stem", "convnext_tiny-ds2"],
+)
+def test_autotune_candidates_compile_where_stride_is_kernel(one_chip, hw, cin, k, cout, stride):
+    """Stride equal to the kernel and no padding: every block the sweep
+    offers for ConvNeXt-T's patchify stem and downsample compiles."""
+    ow = (hw - k) // stride + 1
+    for c in candidate_blocks(ow=ow, cout=cout, cin=cin):
+        _compile_conv(one_chip, hw, cin, k, cout, stride, 0, act="none", **c.as_kwargs())
 
 
 def test_every_autotune_candidate_compiles(one_chip):
@@ -88,7 +105,7 @@ def test_every_autotune_candidate_compiles(one_chip):
 
 def test_matmul_fused_compiles_at_vgg16_fc6(one_chip):
     def f(a, w, b):
-        return matmul_fused(a, w, b, relu=True, interpret=False)
+        return matmul_fused(a, w, b, act="relu", interpret=False)
 
     compiled = jax.jit(f).lower(
         _spec((BATCH, 25088), one_chip),
