@@ -3,6 +3,7 @@
 #
 #   gemm.py / im2col.py      unfused conv-as-GEMM pair (§V-A/V-C)
 #   conv_fused.py            fused implicit-GEMM conv + epilogue (PR 3)
+#   dwconv.py                depthwise conv + epilogue on the vector unit
 #   autotune.py              descriptor-keyed (bm, bn, bk) block tuner
 #   backend.py               per-node backend selection (xla | pallas |
 #                            pallas_fused) with automatic XLA fallback

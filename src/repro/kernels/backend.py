@@ -19,7 +19,9 @@ keys per-layer kernel variants into its throughput model):
     fused XLA route (direct conv + fused epilogue — same operation, no
     patch matrix); shapes `conv_fused.supports` rejects (grouped convs)
     fall back to the XLA route automatically and are counted in
-    ``fallbacks``.
+    ``fallbacks``.  Depthwise nodes take the depthwise kernel
+    (`kernels/dwconv.py`) where `dwconv.supports` accepts the shape, and
+    fall back the same way where it does not.
 
 A backend *spec* is a backend name, a ``{node_name: name}`` mapping
 (missing nodes get ``default``), or a callable ``node_name -> name``.
@@ -37,6 +39,8 @@ import jax.numpy as jnp
 from .autotune import ConvAutotuner
 from .config import interpret_requested, on_tpu
 from .conv_fused import apply_act, conv2d_fused, fused_route_ref, matmul_fused, supports
+from .dwconv import dwconv2d_fused
+from .dwconv import supports as dw_supports
 
 BACKENDS = ("xla", "pallas", "pallas_fused")
 
@@ -163,23 +167,31 @@ class KernelBackend:
         pad: int = 0,
         act: str = "none",
     ) -> Tuple[jnp.ndarray, bool]:
-        """Depthwise convs keep their native grouped-conv implementation on
-        every backend (ARM-CL special-cases them the same way); under
-        ``pallas_fused`` the epilogue still fuses and the fallback is
-        recorded."""
+        """Under ``pallas_fused``, stride-1 ``same`` convs with an odd
+        kernel run the depthwise Pallas kernel (`kernels/dwconv.py`) where
+        Pallas is active, and XLA's fused route off-TPU; other shapes keep
+        XLA's grouped conv with the epilogue fused, and are recorded in
+        ``fallbacks``.  The other backends use the native grouped conv
+        (ARM-CL special-cases depthwise the same way)."""
         from ..cnn import layers as L
 
         choice = self.for_node(name)
-        if choice == "pallas_fused":
+        if choice != "pallas_fused":
+            return L.depthwise_conv2d(x, w, b, stride=stride, pad=pad), False
+        _, h, wd, c = x.shape
+        if not dw_supports(h, wd, c, w.shape[0], stride, pad):
             self.fallbacks[name] = "depthwise"
-            return (
-                fused_route_ref(
-                    x, w, b, stride=stride, pad=pad,
-                    groups=x.shape[-1], act=act,
-                ),
-                True,
+        elif _pallas_active(self.interpret):
+            y = dwconv2d_fused(
+                x, w, b, stride=stride, pad=pad, act=act, interpret=self.interpret
             )
-        return L.depthwise_conv2d(x, w, b, stride=stride, pad=pad), False
+            return y, True
+        return (
+            fused_route_ref(
+                x, w, b, stride=stride, pad=pad, groups=c, act=act,
+            ),
+            True,
+        )
 
     # -------------------------------------------------------------- dense
     def dense(

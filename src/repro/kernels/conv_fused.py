@@ -216,7 +216,8 @@ def _conv_fused_call(
 # ------------------------------------------------------------- public entry
 def supports(fh: int, fw: int, stride: int, groups: int = 1) -> bool:
     """Shapes the fused kernel can tile; everything else falls back to the
-    XLA route (grouped/depthwise convs keep their native implementation)."""
+    XLA route (grouped convs keep their native implementation; depthwise
+    convs have their own kernel, `kernels/dwconv.py`)."""
     return groups == 1 and stride >= 1 and fh >= 1 and fw >= 1
 
 

@@ -166,15 +166,20 @@ def test_backend_grouped_conv_fallback_parity(groups, stride, pad):
     np.testing.assert_allclose(y, want, rtol=RTOL, atol=ATOL)
 
 
+@pytest.mark.parametrize("interpret", [None, True], ids=["xla-route", "kernel"])
 @pytest.mark.parametrize("stride,pad", [(1, 1), (2, 1)])
-def test_backend_depthwise_fallback_parity(stride, pad):
+def test_backend_depthwise_fallback_parity(stride, pad, interpret):
+    """Stride 1 is the depthwise kernel's shape: it runs the kernel
+    (interpret mode) or, off-TPU by default, XLA's fused route, and is no
+    fallback either way; stride 2 keeps XLA's grouped conv and is
+    recorded."""
     c = 6
     x = _arr((2, 9, 9, c))
     w = _arr((3, 3, 1, c))
     b = _arr((c,))
-    kb = resolve_backend("pallas_fused")
+    kb = resolve_backend("pallas_fused", interpret=interpret)
     y, act_done = kb.depthwise("dw", x, w, b, stride=stride, pad=pad, act="relu")
-    assert act_done and kb.fallbacks["dw"] == "depthwise"
+    assert act_done and kb.fallbacks == ({} if stride == 1 else {"dw": "depthwise"})
     want = _conv_oracle(x, w, b, stride, pad, groups=c, relu=True)
     np.testing.assert_allclose(y, want, rtol=RTOL, atol=ATOL)
 

@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.cnn import MODELS
 from repro.kernels.autotune import candidate_blocks
 from repro.kernels.conv_fused import conv2d_fused, matmul_fused
+from repro.kernels.dwconv import dwconv2d_fused
 
 BATCH = 8  # the serving micro-batch chip_smoke.py runs
 
@@ -115,9 +116,33 @@ def test_matmul_fused_compiles_at_vgg16_fc6(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_vgg16_serving_stages_compile(one_chip, monkeypatch):
-    """Every stage program serve("vgg16", backend="pallas_fused") builds,
-    at batch 8 and full width, holds Pallas kernels and no fallback."""
+@pytest.mark.parametrize(
+    "batch,hw,c,k",
+    [
+        (32, 56, 96, 7), (32, 28, 192, 7), (32, 14, 384, 7), (32, 7, 768, 7),
+        (BATCH, 112, 32, 3),
+    ],
+    ids=["convnext_tiny-s1", "convnext_tiny-s2", "convnext_tiny-s3",
+         "convnext_tiny-s4", "mobilenet-dw1"],
+)
+def test_dwconv_compiles(one_chip, batch, hw, c, k):
+    """The depthwise kernel at ConvNeXt-T's four widths at the offline
+    cell's batch, and at MobileNet's largest stride-1 block."""
+    def f(x, w, b):
+        return dwconv2d_fused(x, w, b, pad=k // 2, act="none", interpret=False)
+
+    compiled = jax.jit(f).lower(
+        _spec((batch, hw, hw, c), one_chip),
+        _spec((k, k, 1, c), one_chip),
+        _spec((c,), one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _serving_stages(one_chip, monkeypatch, model, batch):
+    """Compile every stage program serve(model, backend="pallas_fused")
+    builds, at full width: each program's compiled text, and the backend
+    (its ``fallbacks``)."""
     import repro.kernels.backend as backend_mod
     import repro.kernels.config as config_mod
     from repro.core.platform import hikey970
@@ -130,7 +155,7 @@ def test_vgg16_serving_stages_compile(one_chip, monkeypatch):
     monkeypatch.setattr(config_mod, "on_tpu", lambda: True)
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
 
-    graph = MODELS["vgg16"]()
+    graph = MODELS[model]()
     kb = resolve_backend("pallas_fused")
     planner = AutoPlanner(platform=hikey970(), source="synthetic", backend=kb)
     plan = planner.plan(graph, planner.time_matrix(graph))
@@ -141,9 +166,29 @@ def test_vgg16_serving_stages_compile(one_chip, monkeypatch):
         return jax.tree.map(lambda a: _spec(a.shape, one_chip), tree)
 
     params = jax.eval_shape(graph.init, jax.random.PRNGKey(0))
-    env = {"input": jax.ShapeDtypeStruct((BATCH, *graph.input_shape), jnp.float32)}
-    for i, fn in enumerate(fns):
-        compiled = fn.lower(on_chip(params), on_chip(env)).compile()
-        assert "tpu_custom_call" in compiled.as_text(), f"stage {i}"
+    env = {"input": jax.ShapeDtypeStruct((batch, *graph.input_shape), jnp.float32)}
+    texts = []
+    for fn in fns:
+        texts.append(fn.lower(on_chip(params), on_chip(env)).compile().as_text())
         env = jax.eval_shape(fn, params, env)
+    return texts, kb
+
+
+def test_vgg16_serving_stages_compile(one_chip, monkeypatch):
+    """Every stage program serve("vgg16", backend="pallas_fused") builds,
+    at batch 8 and full width, holds Pallas kernels and no fallback."""
+    texts, kb = _serving_stages(one_chip, monkeypatch, "vgg16", BATCH)
+    for i, text in enumerate(texts):
+        assert "tpu_custom_call" in text, f"stage {i}"
+    assert kb.fallbacks == {}
+
+
+def test_convnext_tiny_serving_stages_hold_no_grouped_conv(one_chip, monkeypatch):
+    """At the offline cell's batch of 32, no stage program of ConvNeXt-T
+    keeps an XLA grouped conv: all 18 7x7 depthwise convs are the Pallas
+    kernel's, and nothing fell back."""
+    texts, kb = _serving_stages(one_chip, monkeypatch, "convnext_tiny", 32)
+    for i, text in enumerate(texts):
+        assert "tpu_custom_call" in text, f"stage {i}"
+        assert "feature_group_count" not in text, f"stage {i}"
     assert kb.fallbacks == {}
