@@ -15,8 +15,7 @@ This is the paper's methodology transplanted: measure the deployment
 target, fit the model, let the DSE balance the stages (here the "clusters"
 are XLA inter-op thread groups on one shared CPU — DESIGN.md §2), then
 serve continuously.  Gains come from stage overlap plus batched-dispatch
-amortisation; per-layer kernel times per backend live in
-BENCH_kernels.json (benchmarks/kernels_bench.py).
+amortisation.
 
     PYTHONPATH=src python -m benchmarks.serving_pipeline --backend pallas_fused
 """
@@ -121,7 +120,7 @@ def main():
     ap.add_argument(
         "--backend",
         action="append",
-        choices=("xla", "pallas", "pallas_fused"),
+        choices=("xla", "pallas_fused"),
         help="kernel execution backend for the server run (repeatable); "
         "default compares xla and pallas_fused",
     )

@@ -206,45 +206,30 @@ class Graph:
         return params
 
     # ----------------------------------------------------------- execution
-    def _apply_node(self, n: Node, params, env, gemm_fn=None, backend=None):
+    def _apply_node(self, n: Node, params, env, backend):
         """Execute one node.  ``backend`` (a resolved
-        :class:`repro.kernels.backend.KernelBackend`) routes the major
-        layers through the selected kernel backend and may fuse the
-        node's activation (``act``: none, relu or gelu) into the kernel
-        epilogue; ``gemm_fn`` is the legacy injection point (quantized
-        closures, tests) and wins when set."""
+        :class:`repro.kernels.backend.KernelBackend`) runs the major
+        layers on its route and may fuse the node's activation (``act``:
+        none, relu or gelu) into the kernel epilogue."""
         ins = [env[i] for i in n.inputs]
         x = ins[0]
         act_done = False
         act = n.attrs.get("act", "none")
         if n.kind == "conv":
             p = params[n.name]
-            if backend is not None and gemm_fn is None:
-                y, act_done = backend.conv2d(
-                    n.name, x, p["w"], p["b"], stride=n.attrs["stride"],
-                    pad=n.attrs["pad"], groups=n.attrs.get("groups", 1),
-                    act=act,
-                )
-            else:
-                y = L.conv2d(
-                    x, p["w"], p["b"], stride=n.attrs["stride"], pad=n.attrs["pad"],
-                    groups=n.attrs.get("groups", 1), gemm_fn=gemm_fn,
-                )
+            y, act_done = backend.conv2d(
+                n.name, x, p["w"], p["b"], stride=n.attrs["stride"],
+                pad=n.attrs["pad"], groups=n.attrs.get("groups", 1), act=act,
+            )
         elif n.kind == "depthwise":
             p = params[n.name]
-            if backend is not None and gemm_fn is None:
-                y, act_done = backend.depthwise(
-                    n.name, x, p["w"], p["b"], stride=n.attrs["stride"],
-                    pad=n.attrs["pad"], act=act,
-                )
-            else:
-                y = L.depthwise_conv2d(x, p["w"], p["b"], stride=n.attrs["stride"], pad=n.attrs["pad"])
+            y, act_done = backend.depthwise(
+                n.name, x, p["w"], p["b"], stride=n.attrs["stride"],
+                pad=n.attrs["pad"], act=act,
+            )
         elif n.kind == "fc":
             p = params[n.name]
-            if backend is not None and gemm_fn is None:
-                y, act_done = backend.dense(n.name, x, p["w"], p["b"], act=act)
-            else:
-                y = L.dense(x, p["w"], p["b"], gemm_fn=gemm_fn)
+            y, act_done = backend.dense(n.name, x, p["w"], p["b"], act=act)
         elif n.kind == "pool_max":
             y = L.max_pool(x, n.attrs["window"], n.attrs["stride"], n.attrs["pad"])
         elif n.kind == "pool_avg":
@@ -275,24 +260,21 @@ class Graph:
         env: Dict[str, jnp.ndarray],
         start: int,
         stop: int,
-        gemm_fn=None,
         backend=None,
     ) -> Dict[str, jnp.ndarray]:
         """Execute nodes[start:stop] on the live-tensor environment ``env``
         and return the pruned environment (only tensors still needed by
         nodes >= stop survive — this is what crosses a stage boundary).
 
-        ``backend`` selects the kernel execution backend per node — a
-        name from ``repro.kernels.backend.BACKENDS``, a per-node mapping,
-        a callable, or an already-resolved ``KernelBackend``."""
+        ``backend`` selects the kernel route of the major nodes — a name
+        from ``repro.kernels.backend.BACKENDS`` (None is the ``"xla"``
+        reference) or an already-resolved ``KernelBackend``."""
         from ..kernels.backend import resolve_backend
 
         backend = resolve_backend(backend)
         env = dict(env)
         for n in self.nodes[start:stop]:
-            env[n.name] = self._apply_node(
-                n, params, env, gemm_fn=gemm_fn, backend=backend
-            )
+            env[n.name] = self._apply_node(n, params, env, backend)
         needed = set()
         for n in self.nodes[stop:]:
             needed.update(n.inputs)
@@ -302,10 +284,8 @@ class Graph:
             env = {self.nodes[-1].name: env[self.nodes[-1].name]}
         return env
 
-    def apply(self, params, x: jnp.ndarray, gemm_fn=None, backend=None) -> jnp.ndarray:
-        env = self.apply_range(
-            params, {"input": x}, 0, len(self.nodes), gemm_fn=gemm_fn, backend=backend
-        )
+    def apply(self, params, x: jnp.ndarray, backend=None) -> jnp.ndarray:
+        env = self.apply_range(params, {"input": x}, 0, len(self.nodes), backend=backend)
         return env[self.nodes[-1].name]
 
     # -------------------------------------------------- stage partitioning

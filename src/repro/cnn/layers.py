@@ -1,19 +1,15 @@
 """CNN layer primitives, executed the ARM-CL way: im2col + GEMM.
 
 Data layout is NHWC.  The GEMM route matters: it makes each conv's cost a
-direct function of the (N, K, M) descriptor dims the paper's model uses,
-and it lets the Pallas GEMM kernel (kernels/gemm.py) slot underneath via
-``use_kernel=True``.
+direct function of the (N, K, M) descriptor dims the paper's model uses.
+These are the reference route's primitives (``KernelBackend("xla")``).
 """
 from __future__ import annotations
 
-import math
-from functools import partial
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 def im2col(x: jnp.ndarray, fh: int, fw: int, stride: int, pad: int) -> jnp.ndarray:
@@ -44,14 +40,8 @@ def conv2d(
     stride: int = 1,
     pad: int = 0,
     groups: int = 1,
-    gemm_fn=None,
 ) -> jnp.ndarray:
-    """Convolution as im2col + GEMM.  ``w``: [FH, FW, Cin/groups, Cout].
-
-    ``gemm_fn(a, bmat)`` may be injected (e.g. the Pallas kernel wrapper);
-    defaults to jnp matmul.
-    """
-    gemm = gemm_fn or (lambda a, bm: a @ bm)
+    """Convolution as im2col + GEMM.  ``w``: [FH, FW, Cin/groups, Cout]."""
     bsz, h, wdt, c = x.shape
     fh, fw, cin_g, cout = w.shape
     oh = (h - fh + 2 * pad) // stride + 1
@@ -59,7 +49,7 @@ def conv2d(
     if groups == 1:
         cols = im2col(x, fh, fw, stride, pad)  # [B, N, K]
         filt = w.reshape(fh * fw * c, cout)  # [K, M]
-        out = gemm(cols.reshape(-1, cols.shape[-1]), filt)
+        out = cols.reshape(-1, cols.shape[-1]) @ filt
         out = out.reshape(bsz, oh, ow, cout)
     else:
         # grouped conv: split channels, one GEMM per group (ARM-CL folds the
@@ -69,8 +59,8 @@ def conv2d(
 
         def one_group(xi, wi):
             cols = im2col(xi, fh, fw, stride, pad)
-            return gemm(
-                cols.reshape(-1, cols.shape[-1]), wi.reshape(fh * fw * cin_g, -1)
+            return (
+                cols.reshape(-1, cols.shape[-1]) @ wi.reshape(fh * fw * cin_g, -1)
             ).reshape(bsz, oh, ow, -1)
 
         out = jax.vmap(one_group)(xg, wg)  # [G, B, OH, OW, M/G]
@@ -105,9 +95,8 @@ def depthwise_conv2d(
     return out
 
 
-def dense(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray], gemm_fn=None) -> jnp.ndarray:
-    gemm = gemm_fn or (lambda a, bm: a @ bm)
-    out = gemm(x.reshape(x.shape[0], -1), w)
+def dense(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray]) -> jnp.ndarray:
+    out = x.reshape(x.shape[0], -1) @ w
     return out + b if b is not None else out
 
 

@@ -4,13 +4,12 @@ ARM-CL's QASYMM8 uses asymmetric uint8 with per-tensor (we use per-output-
 channel for weights, standard practice) scale+zero-point.  The paper's
 point is architectural: quantization is *orthogonal* to Pipe-it — it
 changes layer times (the T matrix) but not the scheduling algorithms.  We
-reproduce that: ``quantize_graph_params`` produces int8 weights, and the
-quantized gemm path includes the de/re-quantization overhead the paper
-measures (Fig. 13).
+reproduce that: ``quantize_tensor`` produces uint8 weights, and ``qgemm``
+includes the de/re-quantization overhead the paper measures (Fig. 13).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,38 +50,3 @@ def qgemm(a: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray, zp: jnp.ndarray) 
         preferred_element_type=jnp.int32,
     )
     return acc.astype(jnp.float32) * sa * scale
-
-
-def quantize_graph_params(params: Dict[str, Dict[str, jnp.ndarray]]):
-    """Quantize every weight matrix/filter in a CNN graph's params."""
-    out = {}
-    for name, p in params.items():
-        q, s, z = quantize_tensor(p["w"].reshape(-1, p["w"].shape[-1]), axis=-1)
-        out[name] = {"qw": q, "scale": s, "zp": z, "b": p["b"], "shape": p["w"].shape}
-    return out
-
-
-def make_quant_gemm_fn(qparams_entry):
-    """A gemm_fn closure for Graph.apply(..., gemm_fn=...) built from one
-    layer's quantized params."""
-    qw = qparams_entry["qw"]
-    s = qparams_entry["scale"]
-    z = qparams_entry["zp"]
-    return lambda a, _ignored: qgemm(a, qw, s, z)
-
-
-def make_quant_conv_fn(qparams_entry, *, stride: int = 1, pad: int = 0,
-                       act: str = "none", pallas: bool = False):
-    """The fused-conv counterpart of :func:`make_quant_gemm_fn`: a closure
-    ``x -> y`` executing one quantized conv layer with the requant step
-    fused into the kernel epilogue (`kernels/conv_fused.py`).
-
-    ``pallas=True`` runs the Pallas kernel (TPU; interpret elsewhere per
-    kernels/config.py); the default is the fused XLA lowering, which is
-    what serves off-TPU."""
-    from ..kernels.conv_fused import qconv2d_fused, qfused_route_ref
-
-    qw, s, z = qparams_entry["qw"], qparams_entry["scale"], qparams_entry["zp"]
-    b, shape = qparams_entry["b"], tuple(qparams_entry["shape"])
-    fn = qconv2d_fused if pallas else qfused_route_ref
-    return lambda x: fn(x, qw, s, z, b, shape, stride=stride, pad=pad, act=act)

@@ -1,38 +1,32 @@
-"""Kernel execution backends for the CNN serving hot path.
+"""Kernel execution route for the CNN serving hot path.
 
-Every conv/fc node a `Graph` executes routes through one of three
-backends, selectable per node (ISSUE 3 tentpole; mirrors how Synergy
-keys per-layer kernel variants into its throughput model):
+Every conv, depthwise and fc node a `Graph` executes goes through one
+`KernelBackend`, which holds one decision: the reference route or the
+served one.
 
 ``"xla"``
-    The status-quo route: explicit im2col patch matrix + jnp matmul
-    (`cnn/layers.py`).  Reference semantics and the numerical baseline.
-``"pallas"``
-    The *unfused* Pallas kernels (`kernels/gemm.py` behind
-    `kernels/ops.gemm`): im2col stays explicit, the GEMM is tiled.
-    Off-TPU this resolves to the jnp reference GEMM (ops.py policy), so
-    serving never lands on interpret mode by accident.
+    The reference: explicit im2col patch matrix + jnp matmul, and XLA's
+    grouped conv for depthwise nodes (`cnn/layers.py`); the activation is
+    applied after the op.
 ``"pallas_fused"``
-    The fused implicit-GEMM kernel (`kernels/conv_fused.py`): block-wise
-    VMEM patches, epilogue in the K-flush, (bm, bn, bk) from the
-    `ConvAutotuner` when one is attached.  Off-TPU it resolves to the
-    fused XLA route (direct conv + fused epilogue — same operation, no
-    patch matrix); shapes `conv_fused.supports` rejects (grouped convs)
-    fall back to the XLA route automatically and are counted in
-    ``fallbacks``.  Depthwise nodes take the depthwise kernel
-    (`kernels/dwconv.py`) where `dwconv.supports` accepts the shape, and
-    fall back the same way where it does not.
+    The served route: the fused implicit-GEMM kernel
+    (`kernels/conv_fused.py`) with the activation in its K-flush, (bm,
+    bn, bk) from the `ConvAutotuner` when one is attached; depthwise
+    nodes take the depthwise kernel (`kernels/dwconv.py`) and fc nodes
+    `matmul_fused`.  Where Pallas is not active (off-TPU, no interpret
+    request) each resolves to its fused XLA lowering, `fused_route_ref`.
+    Shapes the kernels cannot tile (grouped convs, depthwise shapes
+    `dwconv.supports` rejects) go to `fused_route_ref` too and are
+    recorded in ``fallbacks``.
 
-A backend *spec* is a backend name, a ``{node_name: name}`` mapping
-(missing nodes get ``default``), or a callable ``node_name -> name``.
-`resolve_backend` turns a spec into a `KernelBackend`; everything above
-`Graph._apply_node` (stage builders, engines, server, planner) just
-threads the spec through.
+`resolve_backend` turns a route name (or None, the reference) into a
+`KernelBackend`; everything above `Graph._apply_node` (stage builders,
+engines, server, planner) threads that one object through.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Mapping, Optional, Tuple, Union
+from typing import Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
 
@@ -42,9 +36,7 @@ from .conv_fused import apply_act, conv2d_fused, fused_route_ref, matmul_fused, 
 from .dwconv import dwconv2d_fused
 from .dwconv import supports as dw_supports
 
-BACKENDS = ("xla", "pallas", "pallas_fused")
-
-BackendSpec = Union[str, Mapping[str, str], Callable[[str], str], "KernelBackend"]
+BACKENDS = ("xla", "pallas_fused")
 
 
 def _pallas_active(interpret: Optional[bool]) -> bool:
@@ -64,39 +56,22 @@ def _pallas_active(interpret: Optional[bool]) -> bool:
 
 @dataclasses.dataclass
 class KernelBackend:
-    """Per-node kernel routing with automatic XLA fallback.
+    """One route (a name from :data:`BACKENDS`) for every major node, with
+    automatic XLA fallback on the served route.
 
-    ``fallbacks`` records nodes the fused kernel declined (shape it
+    ``fallbacks`` records nodes the fused kernels declined (shape they
     cannot tile) as ``{node_name: reason}`` — the observability hook the
     grouped/depthwise tests assert on.
     """
 
-    spec: BackendSpec = "xla"
-    default: str = "xla"
+    route: str = "xla"
     tuner: Optional[ConvAutotuner] = None
     interpret: Optional[bool] = None
     fallbacks: Dict[str, str] = dataclasses.field(default_factory=dict)
 
     def __post_init__(self):
-        if isinstance(self.spec, str) and self.spec not in BACKENDS:
-            raise ValueError(f"unknown backend {self.spec!r}; pick from {BACKENDS}")
-
-    # ------------------------------------------------------------- routing
-    def for_node(self, name: str) -> str:
-        if callable(self.spec):
-            choice = self.spec(name)
-        elif isinstance(self.spec, str):
-            choice = self.spec
-        else:
-            choice = self.spec.get(name, self.default)
-        if choice not in BACKENDS:
-            raise ValueError(f"unknown backend {choice!r} for node {name!r}")
-        return choice
-
-    def _ops_backend(self) -> Optional[str]:
-        # kernels/ops.py vocabulary: None -> platform default (pallas on
-        # TPU, jnp elsewhere); "interpret" -> forced interpret validation.
-        return "interpret" if (self.interpret and not on_tpu()) else None
+        if self.route not in BACKENDS:
+            raise ValueError(f"unknown backend {self.route!r}; pick from {BACKENDS}")
 
     def _blocks(self, desc) -> Dict[str, int]:
         if self.tuner is None:
@@ -121,18 +96,8 @@ class KernelBackend:
         epilogue."""
         from ..cnn import layers as L
 
-        choice = self.for_node(name)
-        if choice == "xla":
+        if self.route == "xla":
             return L.conv2d(x, w, b, stride=stride, pad=pad, groups=groups), False
-        if choice == "pallas":
-            from . import ops
-
-            gemm_fn = lambda a, bm: ops.gemm(a, bm, backend=self._ops_backend())
-            return (
-                L.conv2d(x, w, b, stride=stride, pad=pad, groups=groups, gemm_fn=gemm_fn),
-                False,
-            )
-        # pallas_fused
         fh, fw, _, _ = w.shape
         if not supports(fh, fw, stride, groups):
             # grouped convolution is the only shape supports() rejects today
@@ -171,12 +136,11 @@ class KernelBackend:
         kernel run the depthwise Pallas kernel (`kernels/dwconv.py`) where
         Pallas is active, and XLA's fused route off-TPU; other shapes keep
         XLA's grouped conv with the epilogue fused, and are recorded in
-        ``fallbacks``.  The other backends use the native grouped conv
+        ``fallbacks``.  The reference uses the native grouped conv
         (ARM-CL special-cases depthwise the same way)."""
         from ..cnn import layers as L
 
-        choice = self.for_node(name)
-        if choice != "pallas_fused":
+        if self.route == "xla":
             return L.depthwise_conv2d(x, w, b, stride=stride, pad=pad), False
         _, h, wd, c = x.shape
         if not dw_supports(h, wd, c, w.shape[0], stride, pad):
@@ -205,14 +169,8 @@ class KernelBackend:
     ) -> Tuple[jnp.ndarray, bool]:
         from ..cnn import layers as L
 
-        choice = self.for_node(name)
-        if choice == "xla":
+        if self.route == "xla":
             return L.dense(x, w, b), False
-        if choice == "pallas":
-            from . import ops
-
-            gemm_fn = lambda a, bm: ops.gemm(a, bm, backend=self._ops_backend())
-            return L.dense(x, w, b, gemm_fn=gemm_fn), False
         x2 = x.reshape(x.shape[0], -1)
         bias = jnp.zeros((w.shape[1],), jnp.float32) if b is None else b
         if not _pallas_active(self.interpret):
@@ -236,15 +194,17 @@ class KernelBackend:
 
 
 def resolve_backend(
-    spec: Optional[BackendSpec],
+    spec: Union[None, str, KernelBackend],
     *,
     tuner: Optional[ConvAutotuner] = None,
     interpret: Optional[bool] = None,
-) -> Optional[KernelBackend]:
-    """None passes through (legacy gemm_fn route stays untouched)."""
-    if spec is None or isinstance(spec, KernelBackend):
+) -> KernelBackend:
+    """A resolved backend passes through; a route name, or None for the
+    ``"xla"`` reference, builds one."""
+    if isinstance(spec, KernelBackend):
         return spec
-    return KernelBackend(spec=spec, tuner=tuner, interpret=interpret)
+    route = "xla" if spec is None else spec
+    return KernelBackend(route, tuner=tuner, interpret=interpret)
 
 
 def finish_act(result: Tuple[jnp.ndarray, bool]) -> jnp.ndarray:
@@ -272,9 +232,8 @@ def measure_graph_routes(
     def timed(desc, fn):
         from .autotune import descriptor_key
 
-        route = kb.for_node(desc.name)
         measured[descriptor_key(desc)] = tuner.measure_route(
-            desc, lambda: jax.block_until_ready(fn()), route=route
+            desc, lambda: jax.block_until_ready(fn()), route=kb.route
         )
 
     for desc in graph.descriptors():

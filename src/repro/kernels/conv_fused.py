@@ -6,12 +6,12 @@ GEMM reads it back — for a 3x3 conv that is a 9x write+read amplification
 of the input tensor.  This kernel is the *implicit* formulation: each
 GEMM grid step forms its patch block in VMEM from one padded input row
 and contracts it immediately, so the patch matrix never exists in HBM,
-and the epilogue — bias add, the activation (ReLU or exact GELU), and
-the QASYMM8 requant scale of `cnn/quant.py` — runs inside the K-flush of
-the accumulator instead of as separate HBM round trips.
+and the epilogue — bias add, then the activation (ReLU or exact GELU) —
+runs inside the K-flush of the accumulator instead of as separate HBM
+round trips.
 
 Grid: ``(B, Oh, Ow/bm, Cout/bn, Fh * C/bk)`` with the fused K dimension
-(filter row x channel block) innermost so the f32/i32 accumulator tile
+(filter row x channel block) innermost so the f32 accumulator tile
 stays resident in VMEM scratch across the whole reduction.  The M tile
 ``bm`` spans output columns of one output row (the ARM-CL row-tile ``ts``
 analogue), ``bn`` tiles output channels, ``bk`` tiles input channels;
@@ -87,7 +87,7 @@ def apply_act(y: jnp.ndarray, act: str, *, in_kernel: bool = False) -> jnp.ndarr
 
 # --------------------------------------------------------------- kernel body
 def _conv_fused_kernel(
-    x_ref, w_ref, s_ref, b_ref, o_ref, acc_ref,
+    x_ref, w_ref, b_ref, o_ref, acc_ref,
     *, fw: int, stride: int, bm: int, n_m: int, ext: int, n_k: int, act: str,
 ):
     k = pl.program_id(4)
@@ -114,13 +114,13 @@ def _conv_fused_kernel(
         if r not in phase:
             phase[r] = x_ref[0, 0, r, pl.ds(m0, bm + ext), :]
         win = phase[r][q:q + bm]  # [bm, bk]
-        part = jnp.dot(win, w_ref[0, j], preferred_element_type=acc_ref.dtype)
+        part = jnp.dot(win, w_ref[0, j], preferred_element_type=jnp.float32)
         acc = part if acc is None else acc + part
     acc_ref[...] += acc
 
     @pl.when(k == n_k - 1)
     def _flush():
-        y = acc_ref[...].astype(jnp.float32) * s_ref[0] + b_ref[0]
+        y = acc_ref[...] + b_ref[0]
         o_ref[0, 0] = apply_act(y, act, in_kernel=True).astype(o_ref.dtype)
 
 
@@ -146,18 +146,17 @@ def default_blocks(ow: int, cout: int, cin: int) -> Tuple[int, int, int]:
     jax.jit,
     static_argnames=(
         "fh", "fw", "stride", "block_m", "block_n", "block_k",
-        "act", "interpret", "out_dtype",
+        "act", "interpret",
     ),
 )
 def _conv_fused_call(
-    xp: jnp.ndarray,  # [B, Hp, Wp, C] spatially pre-padded input (any dtype)
-    w4: jnp.ndarray,  # [FH, FW, C, Cout] filter (same dtype domain as xp)
-    scale: jnp.ndarray,  # [Cout] f32 epilogue scale (ones for the f32 path)
+    xp: jnp.ndarray,  # [B, Hp, Wp, C] spatially pre-padded input
+    w4: jnp.ndarray,  # [FH, FW, C, Cout] filter
     bias: jnp.ndarray,  # [Cout] f32
     *,
     fh: int, fw: int, stride: int,
     block_m: int, block_n: int, block_k: int,
-    act: str, interpret: bool, out_dtype,
+    act: str, interpret: bool,
 ) -> jnp.ndarray:
     b, hp, wp, c = xp.shape
     cout = w4.shape[-1]
@@ -177,10 +176,8 @@ def _conv_fused_call(
     # split columns by stride phase: xph[b, h, r, m] = xp[b, h, m*stride + r]
     xph = xp.reshape(b, hp, wq, stride, n_kc * bk).transpose(0, 1, 3, 2, 4)
     w4 = _pad_axis(_pad_axis(w4, 2, n_kc * bk), 3, n_n * bn)
-    scale2 = _pad_axis(scale.reshape(1, -1).astype(jnp.float32), 1, n_n * bn)
     bias2 = _pad_axis(bias.reshape(1, -1).astype(jnp.float32), 1, n_n * bn)
 
-    acc_dtype = jnp.int32 if jnp.issubdtype(xp.dtype, jnp.integer) else jnp.float32
     out = pl.pallas_call(
         functools.partial(
             _conv_fused_kernel,
@@ -203,13 +200,12 @@ def _conv_fused_call(
                 lambda bi, i, jm, j, k: (k // n_kc, 0, k % n_kc, j),
             ),
             pl.BlockSpec((1, bn), lambda bi, i, jm, j, k: (0, j)),
-            pl.BlockSpec((1, bn), lambda bi, i, jm, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, bm, bn), lambda bi, i, jm, j, k: (bi, i, jm, j)),
-        out_shape=jax.ShapeDtypeStruct((b, oh, n_m * bm, n_n * bn), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        out_shape=jax.ShapeDtypeStruct((b, oh, n_m * bm, n_n * bn), xp.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(xph, w4, scale2, bias2)
+    )(xph, w4, bias2)
     return out[:, :, :ow, :cout]
 
 
@@ -248,72 +244,26 @@ def conv2d_fused(
     xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
     bias = jnp.zeros((cout,), jnp.float32) if b is None else b
     return _conv_fused_call(
-        xp, w, jnp.ones((cout,), jnp.float32), bias,
+        xp, w, bias,
         fh=fh, fw=fw, stride=stride,
         block_m=block_m or dm, block_n=block_n or dn, block_k=block_k or dk,
-        act=act, interpret=default_interpret(interpret), out_dtype=x.dtype,
-    )
-
-
-def qconv2d_fused(
-    x: jnp.ndarray,  # [B, H, W, C] float activations
-    qw: jnp.ndarray,  # [FH*FW*C, Cout] uint8 (quant.quantize_graph_params)
-    scale: jnp.ndarray,  # [1, Cout] weight scales
-    zp: jnp.ndarray,  # [1, Cout] weight zero points
-    b: Optional[jnp.ndarray],
-    w_shape: Tuple[int, int, int, int],
-    *,
-    stride: int = 1,
-    pad: int = 0,
-    act: str = "none",
-    block_m: Optional[int] = None,
-    block_n: Optional[int] = None,
-    block_k: Optional[int] = None,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """QASYMM8 conv with the requant step fused into the K-flush.
-
-    Mirrors `quant.qgemm` exactly: activations quantize per-tensor (over
-    the whole batch, as the patch-matrix route does), both operands shift
-    to the zero-point-free int32 domain, the kernel accumulates in int32,
-    and the epilogue applies the merged requant scale ``sa * scale[j]``
-    plus bias (and the activation) before the single f32 write to HBM.
-    """
-    from ..cnn.quant import quantize_tensor
-
-    fh, fw, c, cout = w_shape
-    assert supports(fh, fw, stride), (fh, fw, stride)
-    qa, sa, za = quantize_tensor(x, axis=None)  # per-tensor, like qgemm
-    xq = qa.astype(jnp.int32) - za.astype(jnp.int32)
-    # spatial zero-padding in the shifted domain == float-zero padding
-    # (float 0 quantizes to exactly za)
-    xqp = jnp.pad(xq, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    wq4 = (qw.astype(jnp.int32) - zp.astype(jnp.int32)).reshape(fh, fw, c, cout)
-    merged = (sa * scale).reshape(-1)  # [Cout]
-    bias = jnp.zeros((cout,), jnp.float32) if b is None else b
-    ow = (x.shape[2] - fw + 2 * pad) // stride + 1
-    dm, dn, dk = default_blocks(ow, cout, c)
-    return _conv_fused_call(
-        xqp, wq4, merged, bias,
-        fh=fh, fw=fw, stride=stride,
-        block_m=block_m or dm, block_n=block_n or dn, block_k=block_k or dk,
-        act=act, interpret=default_interpret(interpret), out_dtype=jnp.float32,
+        act=act, interpret=default_interpret(interpret),
     )
 
 
 # ----------------------------------------------------- fused dense (fc) GEMM
-def _matmul_fused_kernel(a_ref, b_ref, s_ref, c_ref, o_ref, acc_ref, *, n_k, act):
+def _matmul_fused_kernel(a_ref, b_ref, c_ref, o_ref, acc_ref, *, n_k, act):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...], preferred_element_type=acc_ref.dtype)
+    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
 
     @pl.when(k_step == n_k - 1)
     def _flush():
-        y = acc_ref[...].astype(jnp.float32) * s_ref[0] + c_ref[0]
+        y = acc_ref[...] + c_ref[0]
         o_ref[...] = apply_act(y, act, in_kernel=True).astype(o_ref.dtype)
 
 
@@ -340,7 +290,6 @@ def matmul_fused(
     bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k)
     a_p = _pad_axis(_pad_axis(a, 0, _ceil_to(m, bm)), 1, _ceil_to(k, bk))
     w_p = _pad_axis(_pad_axis(w, 0, _ceil_to(k, bk)), 1, _ceil_to(n, bn))
-    ones = jnp.ones((1, w_p.shape[1]), jnp.float32)
     bias2 = _pad_axis(bias.reshape(1, -1).astype(jnp.float32), 1, w_p.shape[1])
     n_k = a_p.shape[1] // bk
     grid = (a_p.shape[0] // bm, w_p.shape[1] // bn, n_k)
@@ -351,13 +300,12 @@ def matmul_fused(
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((a_p.shape[0], w_p.shape[1]), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(a_p, w_p, ones, bias2)
+    )(a_p, w_p, bias2)
     return out[:m, :n]
 
 
@@ -380,9 +328,8 @@ def fused_route_ref(
 
     1x1 convolutions ARE the GEMM (the patch "matrix" is a reshape), so
     they skip the convolution lowering entirely: strided-slice + matmul +
-    epilogue, which XLA fuses tighter than its conv path on CPU — the
-    measured win for the 1x1-dominated nets (MobileNet pointwise,
-    SqueezeNet squeeze/expand; BENCH_kernels.json).
+    epilogue, which XLA fuses tighter than its conv path on CPU (MobileNet
+    pointwise, SqueezeNet squeeze/expand).
     """
     if groups == 1 and w.shape[0] == 1 and w.shape[1] == 1 and pad == 0:
         bsz = x.shape[0]
@@ -398,38 +345,6 @@ def fused_route_ref(
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=groups,
     )
-    if b is not None:
-        y = y + b
-    return apply_act(y, act)
-
-
-def qfused_route_ref(
-    x: jnp.ndarray,
-    qw: jnp.ndarray,
-    scale: jnp.ndarray,
-    zp: jnp.ndarray,
-    b: Optional[jnp.ndarray],
-    w_shape: Tuple[int, int, int, int],
-    *,
-    stride: int = 1,
-    pad: int = 0,
-    act: str = "none",
-) -> jnp.ndarray:
-    """XLA lowering of :func:`qconv2d_fused`: the same per-tensor activation
-    quantization, int32 direct convolution in the zero-point-free domain,
-    and merged-scale epilogue — no patch matrix, one fused computation."""
-    from ..cnn.quant import quantize_tensor
-
-    fh, fw, c, cout = w_shape
-    qa, sa, za = quantize_tensor(x, axis=None)
-    xq = qa.astype(jnp.int32) - za.astype(jnp.int32)
-    wq = (qw.astype(jnp.int32) - zp.astype(jnp.int32)).reshape(w_shape)
-    acc = jax.lax.conv_general_dilated(
-        xq, wq, (stride, stride), [(pad, pad), (pad, pad)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        preferred_element_type=jnp.int32,
-    )
-    y = acc.astype(jnp.float32) * (sa * scale).reshape(1, 1, 1, -1)
     if b is not None:
         y = y + b
     return apply_act(y, act)

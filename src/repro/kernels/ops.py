@@ -1,17 +1,17 @@
-"""Public jit'd wrappers for the Pallas kernels, with backend selection.
+"""Public wrappers for the scaling-substrate Pallas kernels (flash decode,
+SSD scan), with backend selection.
 
 ``backend``:
-  "pallas"     — compiled Pallas (real TPU).
+  "compiled"   — compiled Pallas (real TPU).
   "interpret"  — Pallas interpret mode (CPU validation; kernel body runs
                  in Python, numerically identical to TPU semantics).
   "jnp"        — the pure-jnp reference path (fast on CPU; used by default
                  for CPU benchmarks so wall-times are meaningful).
 
-Default resolves by platform: TPU -> pallas, CPU -> jnp.
+Default resolves by platform: TPU -> compiled, CPU -> jnp.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -19,29 +19,10 @@ import jax.numpy as jnp
 
 from . import ref
 from .flash_decode import flash_decode as _flash_decode_pallas
-from .gemm import gemm as _gemm_pallas
-from .im2col import im2col as _im2col_pallas
 
 
 def _default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
-
-
-def gemm(a: jnp.ndarray, b: jnp.ndarray, backend: Optional[str] = None) -> jnp.ndarray:
-    backend = backend or _default_backend()
-    if backend == "jnp":
-        return ref.gemm_ref(a, b)
-    return _gemm_pallas(a, b, interpret=(backend == "interpret"))
-
-
-def im2col(
-    x: jnp.ndarray, fh: int, fw: int, stride: int = 1, pad: int = 0,
-    backend: Optional[str] = None,
-) -> jnp.ndarray:
-    backend = backend or _default_backend()
-    if backend == "jnp":
-        return ref.im2col_ref(x, fh, fw, stride, pad)
-    return _im2col_pallas(x, fh, fw, stride, pad, interpret=(backend == "interpret"))
+    return "compiled" if jax.default_backend() == "tpu" else "jnp"
 
 
 def flash_decode(

@@ -9,31 +9,6 @@ import jax
 import jax.numpy as jnp
 
 
-def gemm_ref(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """f32-accumulating matmul oracle: [M,K] @ [K,N] -> [M,N]."""
-    return jnp.dot(a, b, preferred_element_type=jnp.float32).astype(a.dtype)
-
-
-def im2col_ref(x: jnp.ndarray, fh: int, fw: int, stride: int, pad: int) -> jnp.ndarray:
-    """[H,W,C] -> [OH*OW, FH*FW*C], patch features ordered (fh, fw, c)."""
-    h, w, c = x.shape
-    xp = jnp.pad(x, ((pad, pad), (pad, pad), (0, 0)))
-    oh = (h - fh + 2 * pad) // stride + 1
-    ow = (w - fw + 2 * pad) // stride + 1
-    rows = []
-    for i in range(fh):
-        for j in range(fw):
-            rows.append(
-                jax.lax.slice(
-                    xp, (i, j, 0), (i + stride * (oh - 1) + 1, j + stride * (ow - 1) + 1, c),
-                    (stride, stride, 1),
-                )
-            )
-    # [OH, OW, FH*FW, C] -> [OH*OW, FH*FW*C]
-    stacked = jnp.stack(rows, axis=2)
-    return stacked.reshape(oh * ow, fh * fw * c)
-
-
 def flash_decode_ref(
     q: jnp.ndarray,  # [Hq, D]
     k: jnp.ndarray,  # [S, D]

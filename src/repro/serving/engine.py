@@ -51,11 +51,11 @@ def build_stage_fns(
     charges for).  The functions are shape-polymorphic over the batch
     dimension — XLA compiles one executable per distinct batch size.
 
-    ``backend`` selects the kernel execution backend for the stage's
-    major layers (``repro.kernels.backend``: "xla", "pallas",
-    "pallas_fused", a per-node mapping/callable, or a resolved
-    ``KernelBackend``).  The spec is resolved ONCE here so tuner state
-    and fallback bookkeeping are shared across stages.
+    ``backend`` selects the kernel route of the stage's major layers
+    (``repro.kernels.backend``: "xla", the reference and the default,
+    "pallas_fused", or a resolved ``KernelBackend``).  It is resolved
+    ONCE here so tuner state and fallback bookkeeping are shared across
+    stages.
     """
     from ..kernels.backend import resolve_backend
 

@@ -104,9 +104,9 @@ class MultiModelServer:
         admission (each pipeline's bounded queues still push back).
     stage_fn_builders : optional ``{model: (graph, plan) -> [stage_fn]}``
         overrides (fake-stage benchmarks and the stress tests).
-    backend : kernel execution backend spec shared by every model's stage
-        executables; pass a resolved ``KernelBackend`` to share tuner
-        state across models.
+    backend : kernel route shared by every model's stage executables
+        ("xla" or "pallas_fused"); pass a resolved ``KernelBackend`` to
+        share tuner state across models.
     tuner : the shared :class:`~repro.kernels.autotune.ConvAutotuner`
         whose route cache planned this partition (kept for re-planning).
     """

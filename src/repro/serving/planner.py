@@ -76,9 +76,9 @@ class AutoPlanner:
         (beyond-paper work_flow-over-all-pipelines, DESIGN.md §2) or
         "best" (both, keep the higher-throughput plan).
     source : where predicted layer times come from (see module docstring).
-    backend : kernel execution backend spec for the stage executables
-        ("xla" | "pallas" | "pallas_fused" | per-node mapping | resolved
-        ``KernelBackend``); threaded into ``build_stage_fns``.
+    backend : kernel route of the stage executables ("xla" |
+        "pallas_fused" | resolved ``KernelBackend``); threaded into
+        ``build_stage_fns``.
     measured : {autotuner descriptor key: seconds} route measurements
         (``measure_graph_routes``); they override the Eq. 5 regression in
         the predictor (``LayerTimePredictor(measured=...)``) so the time
@@ -330,9 +330,9 @@ def serve(
     hot-swaps the layer allocation when the bottleneck drifts
     (``server.monitor`` holds it; ``server.stop()`` shuts it down).
 
-    ``backend`` selects the kernel execution backend for every stage
-    executable ("xla" | "pallas" | "pallas_fused", or per-node — see
-    :mod:`repro.kernels.backend`).  ``autotune=True`` attaches a
+    ``backend`` selects the kernel route of every stage executable:
+    "xla", the reference and the default, or "pallas_fused", the fused
+    kernels (see :mod:`repro.kernels.backend`).  ``autotune=True`` attaches a
     :class:`repro.kernels.autotune.ConvAutotuner` (or pass an existing
     one via ``tuner``): the tuner measures each layer's serving route
     once (JSON-cached per device kind), picks fused block sizes, and the
@@ -410,11 +410,9 @@ def serve(
         from ..kernels.autotune import ConvAutotuner
 
         tuner = ConvAutotuner()
-    if backend is None and tuner is not None:
-        backend = "xla"  # measurements must reflect the route that serves
     kb = resolve_backend(backend, tuner=tuner)
     measured = None
-    if kb is not None and tuner is not None and time_matrix is None:
+    if tuner is not None and time_matrix is None:
         # skipped when the caller pins an explicit time matrix — the
         # measurements would be dead startup latency
         measured = measure_graph_routes(graph, kb, tuner)
@@ -549,11 +547,9 @@ def _serve_multi(
         from ..kernels.autotune import ConvAutotuner
 
         tuner = ConvAutotuner()
-    if backend is None and tuner is not None:
-        backend = "xla"  # measurements must reflect the route that serves
     kb = resolve_backend(backend, tuner=tuner)
     measured = None
-    if kb is not None and tuner is not None and time_matrix is None:
+    if tuner is not None and time_matrix is None:
         for entry in registry:  # one shared cache: common shapes time once
             measure_graph_routes(entry.graph, kb, tuner)
         measured = tuner.route_seconds()

@@ -172,9 +172,9 @@ class PipelineServer:
         The adaptive tests inject fake-stage builders here (real outputs
         plus a scripted service delay) so the whole control loop can run
         against known timings.
-    backend : kernel execution backend spec for the stage executables
-        ("xla" | "pallas" | "pallas_fused", a per-node mapping/callable,
-        or a resolved ``repro.kernels.backend.KernelBackend``).  Resolved
+    backend : kernel route of the stage executables: "xla" (the
+        reference, and the default), "pallas_fused", or a resolved
+        ``repro.kernels.backend.KernelBackend``.  Resolved
         once and reused across plan swaps; ignored when a custom
         ``stage_fn_builder`` is injected.
     recovery : optional :class:`repro.serving.faults.RecoveryPolicy`.

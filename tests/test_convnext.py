@@ -14,7 +14,7 @@ from repro.kernels.backend import KernelBackend
 from repro.kernels.conv_fused import apply_act, conv2d_fused, erf_f32, fused_route_ref
 
 HW = 64
-RTOL, ATOL = 1e-4, 1e-5  # the fused-backend parity bound of test_conv_fused.py
+RTOL, ATOL = 1e-4, 1e-5  # the kernel-level bound of test_conv_fused.py
 F32_ULP = float(np.finfo(np.float32).eps)  # one ulp of 1.0
 
 

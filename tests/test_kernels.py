@@ -1,5 +1,4 @@
 """Pallas kernels vs pure-jnp oracles — shape/dtype sweeps in interpret mode."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,81 +6,12 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.flash_decode import flash_decode
-from repro.kernels.gemm import gemm
-from repro.kernels.im2col import im2col
 
 RNG = np.random.default_rng(42)
 
 
 def _arr(shape, dtype=jnp.float32, scale=1.0):
     return jnp.asarray(RNG.standard_normal(shape) * scale, dtype)
-
-
-# ------------------------------------------------------------------- GEMM
-@pytest.mark.parametrize(
-    "m,k,n", [(8, 8, 8), (128, 128, 128), (130, 70, 50), (1, 256, 512), (257, 129, 3)]
-)
-def test_gemm_shapes_f32(m, k, n):
-    a, b = _arr((m, k)), _arr((k, n))
-    got = gemm(a, b, block_m=64, block_n=64, block_k=64, interpret=True)
-    np.testing.assert_allclose(got, ref.gemm_ref(a, b), rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_gemm_dtypes(dtype):
-    a, b = _arr((96, 64), dtype), _arr((64, 80), dtype)
-    got = gemm(a, b, block_m=32, block_n=32, block_k=32, interpret=True)
-    want = ref.gemm_ref(a, b)
-    tol = 1e-4 if dtype == jnp.float32 else 5e-2
-    np.testing.assert_allclose(
-        got.astype(jnp.float32), want.astype(jnp.float32), rtol=tol, atol=tol
-    )
-
-
-@given(
-    st.integers(min_value=1, max_value=96),
-    st.integers(min_value=1, max_value=96),
-    st.integers(min_value=1, max_value=96),
-    st.sampled_from([16, 32, 64]),
-)
-@settings(max_examples=15, deadline=None)
-def test_gemm_property_random_shapes(m, k, n, blk):
-    a, b = _arr((m, k)), _arr((k, n))
-    got = gemm(a, b, block_m=blk, block_n=blk, block_k=blk, interpret=True)
-    np.testing.assert_allclose(got, ref.gemm_ref(a, b), rtol=1e-4, atol=1e-4)
-
-
-def test_gemm_blocking_invariance():
-    a, b = _arr((100, 60)), _arr((60, 90))
-    o1 = gemm(a, b, block_m=16, block_n=16, block_k=16, interpret=True)
-    o2 = gemm(a, b, block_m=64, block_n=64, block_k=64, interpret=True)
-    np.testing.assert_allclose(o1, o2, rtol=1e-5, atol=1e-5)
-
-
-# ----------------------------------------------------------------- im2col
-@pytest.mark.parametrize(
-    "hw,c,fh,stride,pad",
-    [(8, 3, 3, 1, 1), (12, 4, 5, 2, 2), (7, 8, 1, 1, 0), (14, 2, 7, 2, 3), (9, 5, 3, 3, 1)],
-)
-def test_im2col_matches_ref(hw, c, fh, stride, pad):
-    x = _arr((hw, hw, c))
-    got = im2col(x, fh, fh, stride, pad, interpret=True)
-    want = ref.im2col_ref(x, fh, fh, stride, pad)
-    assert got.shape == want.shape
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-
-
-def test_im2col_then_gemm_equals_conv():
-    """Kernel composition reproduces the convolution itself."""
-    x = _arr((10, 10, 6))
-    w = _arr((3, 3, 6, 8))
-    cols = im2col(x, 3, 3, 1, 1, interpret=True)
-    out = gemm(cols, w.reshape(-1, 8), block_m=32, block_n=32, block_k=32, interpret=True)
-    want = jax.lax.conv_general_dilated(
-        x[None], w, (1, 1), [(1, 1), (1, 1)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )[0].reshape(-1, 8)
-    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-4)
 
 
 # ------------------------------------------------------------ flash decode
